@@ -112,6 +112,7 @@ def bench_cell(n_clients: int, budget_s: float, serve_every: int,
     trainer = ClusterTrainer()
     runtime = trainer.build_runtime(spec)
     runtime.proc_ready_timeout_s = 180.0
+    runtime.join_platform = platform
     join = spawn_join_process(runtime.listen_address, workers=1,
                               platform=platform)
     stop = threading.Event()
@@ -141,6 +142,8 @@ def bench_cell(n_clients: int, budget_s: float, serve_every: int,
             "applied": a["applied"],
             "serve_wall_s": round(serve_s, 3),
             "grads_per_s": round(a["applied"] / max(serve_s, 1e-9), 1),
+            "worker_platforms": sorted(set(
+                res.extra["placement"]["worker_platforms"].values())),
         },
         "client_stats": [r for r in records if r],
         "serving": res.extra.get("serving"),
@@ -167,8 +170,8 @@ def main(argv=None):
         else ([0, 2] if args.quick else [0, 2, 8])
     budget = args.budget if args.budget else (8.0 if args.quick else 12.0)
 
-    import jax
-    platform = None if jax.default_backend() == "cpu" else "cpu"
+    from repro.cluster.mptransport import worker_process_platform
+    platform = worker_process_platform()
 
     cells = []
     for n in grid_clients:

@@ -293,8 +293,10 @@ def bench_transport_cell(fleet: int, K: int, transport: str,
     trainer = ClusterTrainer()
     if transport == "host":
         from repro.cluster.hostlink import spawn_join_process
-        platform = None if jax.default_backend() == "cpu" else "cpu"
+        from repro.cluster.mptransport import worker_process_platform
+        platform = worker_process_platform()
         runtime = trainer.build_runtime(spec)
+        runtime.join_platform = platform
         # the trainer's 10-minute interactive join window is wrong for
         # a scripted bench: a join group that dies at startup should
         # fail the cell in ~2 minutes, not stall the whole grid
@@ -327,7 +329,11 @@ def bench_transport_cell(fleet: int, K: int, transport: str,
             "computed": a["computed"],
             "serve_wall_s": round(serve_s, 3),
             "total_wall_s": round(res.wall_s, 3),
-            "grads_per_s": round(a["applied"] / max(serve_s, 1e-9), 1)}
+            "grads_per_s": round(a["applied"] / max(serve_s, 1e-9), 1),
+            # where the gradients were computed: a worker process under
+            # an accelerator parent computes on the CPU
+            "worker_platforms": sorted(set(
+                res.extra["placement"]["worker_platforms"].values()))}
 
 
 def run_transport_grid(fleets, ks, transports, max_gradients: int,

@@ -8,12 +8,15 @@ spec travels over the wire — see repro.cluster.hostlink).
 import sys
 
 if __name__ == "__main__":
+    # Importing repro.api pulls in jax, after which the device topology
+    # and the compile-cache directory are frozen — set both first
+    # (repro.launch._xla_env is jax-free).
+    from repro.launch._xla_env import (force_host_device_count,
+                                       use_compile_cache)
+    use_compile_cache()
     argv = sys.argv[1:]
     if argv and argv[0] == "dryrun":
-        # Importing repro.api pulls in jax, after which the device
-        # topology is frozen — force the dry-run's 512 host devices
-        # first (repro.launch._xla_env is jax-free).
-        from repro.launch._xla_env import force_host_device_count
+        # the dry-run's 512 forced host devices
         force_host_device_count()
     from repro.api.cli import main
     sys.exit(main(argv))

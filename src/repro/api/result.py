@@ -21,6 +21,15 @@ and consumers may rely on their *shape*, not just their presence:
     with at least ``t`` and ``event``).
   * ``start_version`` — server version at t=0 (non-zero after resume).
   * ``serve_wall_s`` — the serving-window denominator for grads/sec.
+  * ``setup_s``      — seconds before the serving clock started:
+    worker-gradient compile + server warm-up (+ fleet assembly on
+    ``proc``/``host``).
+  * ``placement``    — where the work ran: ``platform`` and
+    ``device_kind`` of the server's device, ``flush`` (``"pallas"``,
+    ``"pallas_interpret"`` or ``"jnp"``), and ``worker_platforms``
+    (worker id -> the platform its gradients were computed on;
+    ``"unreported"`` for joined hosts).  A ``proc`` worker under an
+    accelerator parent computes on ``"cpu"`` — its rate is a CPU rate.
   * ``serving``      — **always present**: ``clients``,
     ``rejected_peers``, ``serve_every``, ``stats_clients``,
     ``per_client``.  Transports without a serving plane report the
@@ -179,4 +188,6 @@ class RunResult:
                    # is ready) — the denominator for gradients/sec that
                    # is comparable across transports, unlike wall_s
                    # which includes worker-process startup
-                   "serve_wall_s": float(cres.wall_s)})
+                   "serve_wall_s": float(cres.wall_s),
+                   "setup_s": float(cres.setup_s),
+                   "placement": dict(cres.placement or {})})

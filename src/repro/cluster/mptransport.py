@@ -166,6 +166,7 @@ import sys
 import tempfile
 import threading
 import time
+import warnings
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import ml_dtypes
@@ -1531,15 +1532,32 @@ class SocketWorkerClient:
 # ================================================== process launcher
 
 
+def worker_process_platform() -> str:
+    """``JAX_PLATFORMS`` for a worker process spawned on this host:
+    always ``"cpu"``.  One accelerator serves one process, and this one
+    already holds it (or there is none), so a same-host child computes
+    on the host CPU.  Under an accelerator parent that is the fallback
+    that would pass for a chip run, so it warns, and the runtime
+    records the platform per worker (``RunResult.extra["placement"]``).
+    Pinning one worker process to each chip is still to be built."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        warnings.warn(
+            f"worker processes compute on the CPU: this process holds "
+            f"the {backend} device and one accelerator serves one "
+            "process", RuntimeWarning, stacklevel=2)
+    return "cpu"
+
+
 @dataclasses.dataclass
 class ProcWorkerConfig:
     """Everything a worker process needs to rebuild its world: the
     experiment spec (to rebuild the workload via the ``SIM_WORKLOADS``
     registry — code does not cross the process boundary, only this
     picklable description does), its identity/shard, and the hub
-    address.  ``platform`` forces ``JAX_PLATFORMS`` in the child (set
-    to ``"cpu"`` when the parent holds an exclusive accelerator — two
-    processes cannot share one TPU)."""
+    address.  ``platform`` forces ``JAX_PLATFORMS`` in the child (see
+    :func:`worker_process_platform`)."""
     spec: Dict[str, Any]
     worker_id: int
     generation: int

@@ -65,7 +65,8 @@ from repro.checkpoint import (latest_step, load_opt_state,
                               restore_checkpoint, save_checkpoint)
 from repro.cluster.faults import FaultPlan
 from repro.cluster.mptransport import (ProcTransport, ProcWorkerConfig,
-                                       SocketTransport)
+                                       SocketTransport,
+                                       worker_process_platform)
 from repro.cluster.server import ParameterServer
 from repro.cluster.transport import TRANSPORTS, InProcTransport, Transport
 from repro.cluster.worker import Worker
@@ -98,6 +99,11 @@ class ClusterResult:
     # cluster backend (empty-shaped when the transport has no serving
     # plane), so consumers key on content, not key presence
     serving: Optional[Dict[str, Any]] = None
+    # where the work ran: the server's platform and device kind, which
+    # flush path its aggregator took, and the platform each worker id
+    # computed its gradients on ("unreported" for joined hosts)
+    placement: Optional[Dict[str, Any]] = None
+    setup_s: float = 0.0         # pre-clock compile + warm-up seconds
     # telemetry plane: the obs summary (counters / gauges / histograms)
     # plus a ledger_check block cross-checking the telemetry counters
     # against the conservation ledger
@@ -297,6 +303,11 @@ class ClusterRuntime:
         self._workers: Dict[int, Worker] = {}
         self._all_workers: List[Worker] = []
         self._generation: Dict[int, int] = {}
+        self._worker_platforms: Dict[int, str] = {}
+        # the platform joined (host) workers compute on, where the
+        # caller launched them itself (``spawn_join_process(platform=)``);
+        # None: a joined host picks its own and the wire does not carry it
+        self.join_platform: Optional[str] = None
         self.events: List[Dict[str, Any]] = []
         self._control_errors: List[str] = []
         self._t0 = 0.0
@@ -342,16 +353,15 @@ class ClusterRuntime:
             # a worker that cannot yet contribute — an inproc respawn
             # is instant, and a real cluster's barrier also only counts
             # nodes that have joined
+            platform = worker_process_platform()
             self.transport.spawn_worker(ProcWorkerConfig(
                 spec=self.spec_dict, worker_id=wid, generation=gen,
                 num_workers=self.num_workers, mode=self.mode,
                 straggle_s=self.faults.straggle_s(wid), seed=self.seed,
-                batch=self.batch,
-                # two processes can't share one accelerator: children
-                # fall back to CPU unless the parent is CPU already
-                platform=None if jax.default_backend() == "cpu"
-                else "cpu"))
+                batch=self.batch, platform=platform))
+            self._worker_platforms[wid] = platform
             return
+        self._worker_platforms[wid] = jax.default_backend()
         batches = shard_iterator(self.x_tr, self.y_tr, wid,
                                  self.num_workers, self.batch,
                                  seed=self.seed, generation=gen)
@@ -407,6 +417,8 @@ class ClusterRuntime:
         # legitimate holder of the worker id's shard
         if self.transport_kind == "host":
             if gen >= self._generation.get(wid, -1):
+                self._worker_platforms[wid] = (self.join_platform
+                                               or "unreported")
                 # grow BEFORE register: the staging buffer must cover
                 # the live fleet when this worker's first sync round
                 # fills
@@ -626,7 +638,8 @@ class ClusterRuntime:
                 self.transport.close()
 
     def _run(self) -> ClusterResult:
-        self._t0 = time.monotonic()     # provisional: pre-barrier events
+        setup_t0 = time.monotonic()
+        self._t0 = setup_t0             # provisional: pre-barrier events
         #                                 (listening, ...) get small ts;
         #                                 reset when the clock starts
         start_version = 0
@@ -753,6 +766,7 @@ class ClusterRuntime:
                             f"{self.proc_ready_timeout_s}s")
 
             self._t0 = time.monotonic()
+            setup_s = self._t0 - setup_t0
             if self.transport_kind in ("proc", "host"):
                 self.transport.release_params()     # the starting gun
             if start_version:
@@ -899,6 +913,15 @@ class ClusterRuntime:
                            and accounting["computed"]
                            == ingested + accounting["in_flight"]),
         }
+        agg = self.server.agg
+        placement = {
+            "platform": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "flush": ("jnp" if not agg.use_pallas else
+                      "pallas_interpret" if agg.interpret else "pallas"),
+            "worker_platforms": {str(w): p for w, p in
+                                 sorted(self._worker_platforms.items())},
+        }
         return ClusterResult(
             times=np.asarray(times), train_loss=np.asarray(tr),
             test_loss=np.asarray(te), test_acc=np.asarray(acc),
@@ -906,4 +929,4 @@ class ClusterRuntime:
             mode=self.mode, start_version=start_version,
             accounting=accounting, events=list(self.events),
             final_params=final_params, wall_s=wall_s, serving=serving,
-            telemetry=telemetry)
+            telemetry=telemetry, placement=placement, setup_s=setup_s)

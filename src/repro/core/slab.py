@@ -154,8 +154,13 @@ class SlabCodec:
         return self._encode_as(tree, self.slab_dtype)
 
     def _decode_impl(self, slab):
+        # the barrier pins slice-then-reshape: without it XLA hoists a
+        # leaf's reshape above its slice, reshaping the WHOLE slab to
+        # the leaf's minor dims — for an (.., 4, 2) leaf the TPU tiling
+        # pads that copy 64x, which at xlstm-350m's P is 56 GB of HBM
         leaves = [
-            slab[off:off + n].reshape(shape).astype(dtype)
+            jax.lax.optimization_barrier(slab[off:off + n])
+            .reshape(shape).astype(dtype)
             for off, n, shape, dtype in zip(self.offsets, self.sizes,
                                             self.shapes, self.dtypes)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
@@ -327,7 +332,6 @@ class SlabAggregator:
                 for n, d in zip(self.chunk_sizes, self._chunk_devices())]
         # published params slab: always a fresh executable output
         self._pub = codec.encode(params)
-        self._zero_row = jnp.zeros((codec.padded_size,), codec.slab_dtype)
         self._init_opt_state()
 
     def _init_opt_state(self) -> None:
@@ -361,12 +365,13 @@ class SlabAggregator:
         # both branches reduce via zero-weight masking — rows past the
         # live count hold weight 0 and contribute exactly +0.0 — which
         # is what lets ONE executable serve every buffer size k.  The
-        # reduction always runs in float32 (bf16 staging rows are
-        # upcast here; for f32 rows the cast disappears at trace time)
-        rows = staging if staging.dtype == jnp.float32 \
-            else staging.astype(jnp.float32)
+        # reduction always runs in float32: the kernel upcasts bf16 rows
+        # per tile (widening the staging buffer in HBM first would cost
+        # K_max·P·4 bytes of scratch), the jnp fold upcasts here (for
+        # f32 rows the cast disappears at trace time)
         if self.use_pallas:
-            agg = flush_pallas(rows, weights, interpret=self.interpret)
+            agg = flush_pallas(staging, weights, out_dtype=jnp.float32,
+                               interpret=self.interpret)
         else:
             # jnp fallback: a statically unrolled masked fold in staging
             # order — structurally identical to the legacy per-leaf fold
@@ -374,18 +379,15 @@ class SlabAggregator:
             # round mean bitwise-equal to the pre-slab server.  (A
             # fori_loop over only the k live rows compiles to different
             # FMA contraction and drifts by 1 ulp.)
+            rows = staging.astype(jnp.float32)
             agg = weights[0] * rows[0]
             for i in range(1, self.k_max):
                 agg = agg + weights[i] * rows[i]
         new = pslab - scale * (agg / jnp.sum(weights))
         # the second output is the published copy: a fresh buffer that
         # does NOT alias the donated input (tests/test_slab.py guards
-        # this against XLA deciding to alias the two outputs).  In bf16
-        # mode the publish IS the narrowing cast; the f32 master stays
-        # exact
-        if self.codec.slab_dtype == jnp.dtype(jnp.float32):
-            return new, new + 0.0
-        return new, new.astype(self.codec.slab_dtype)
+        # this against XLA deciding to alias the two outputs)
+        return new, self._published(new)
 
     def _published(self, new):
         """The publish copy of a freshly updated master slab: a fresh
@@ -399,8 +401,7 @@ class SlabAggregator:
         """The flush's weighted-mean gradient on the jnp path: the same
         statically unrolled masked f32 fold as the SGD flush (same muls,
         adds, order — deterministic), normalized by the weight sum."""
-        rows = staging if staging.dtype == jnp.float32 \
-            else staging.astype(jnp.float32)
+        rows = staging.astype(jnp.float32)
         agg = weights[0] * rows[0]
         for i in range(1, self.k_max):
             agg = agg + weights[i] * rows[i]
@@ -412,11 +413,9 @@ class SlabAggregator:
         # params' = params - scale·mu'.  ``pslab`` and ``mu`` are
         # donated; the moments stay f32 whatever the staging dtype
         if self.use_pallas:
-            rows = staging if staging.dtype == jnp.float32 \
-                else staging.astype(jnp.float32)
             upd, mu_new = flush_momentum_pallas(
-                rows, weights / jnp.sum(weights), mu, self.opt.beta1,
-                interpret=self.interpret)
+                staging, weights / jnp.sum(weights), mu, self.opt.beta1,
+                out_dtype=jnp.float32, interpret=self.interpret)
             new = pslab - scale * upd
             count_new = count + 1
         else:
@@ -433,12 +432,10 @@ class SlabAggregator:
         # count carried in state (the shared step-count convention of
         # repro.optim).  ``pslab``/``mu``/``nu`` are donated
         if self.use_pallas:
-            rows = staging if staging.dtype == jnp.float32 \
-                else staging.astype(jnp.float32)
             c = count + 1
             bc1, bc2 = bias_correction(c, self.opt.beta1, self.opt.beta2)
             new, mu_new, nu_new = flush_adamw_pallas(
-                rows, weights / jnp.sum(weights), pslab, mu, nu,
+                staging, weights / jnp.sum(weights), pslab, mu, nu,
                 bc1, bc2, scale, b1=self.opt.beta1, b2=self.opt.beta2,
                 eps=self.opt.eps, weight_decay=self.opt.weight_decay,
                 interpret=self.interpret)
@@ -619,7 +616,8 @@ class SlabAggregator:
         pre-slab server's one compile per K in 1..num_workers).  The
         warmup flush uses scale=0 over a zero row, so the params are
         bitwise unchanged."""
-        self.stage(self._zero_row, 0)
+        self.stage(jnp.zeros((self.codec.padded_size,),
+                             self.codec.slab_dtype), 0)
         self.flush_apply(np.ones((1,), np.float32), 0.0)
         # a zero-gradient scale-0 flush leaves params AND moments
         # bitwise unchanged, but it does tick the update count — rewind

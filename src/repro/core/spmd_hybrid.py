@@ -69,6 +69,7 @@ def merge_replicas(params_R, alpha: float = 1.0):
 
 
 def merge_replicas_slab(params_R, alpha: float = 1.0, *,
+                        mesh: Optional[Mesh] = None,
                         use_pallas: Optional[bool] = None,
                         interpret: Optional[bool] = None):
     """The hybrid flush on the slab path: replicas are encoded into an
@@ -76,23 +77,70 @@ def merge_replicas_slab(params_R, alpha: float = 1.0, *,
     reduction the parameter server's flush uses
     (:func:`repro.kernels.ops.hybrid_flush` → ``flush_pallas`` on TPU,
     the jnp reference elsewhere), then decoded and α-blended exactly
-    like :func:`merge_replicas`."""
+    like :func:`merge_replicas`.
+
+    With ``mesh`` (traced under ``jit`` with the replicas laid out over
+    the mesh, see :func:`rejoin_replicas`) the slab matrix is spread
+    along P over every device of the mesh, and each device reduces all
+    R rows of its own P range: the reduction is elementwise along P, so
+    the result is the same, and no device ever holds R whole
+    replicas."""
     from repro.core.slab import slab_codec
     from repro.kernels import ops
+    from repro.kernels.hybrid_aggregate import TILE_P
 
-    codec = slab_codec(jax.tree.map(lambda p: p[0], params_R))
+    codec = slab_codec(jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype), params_R))
     R = jax.tree.leaves(params_R)[0].shape[0]
     rows = jax.vmap(codec.encode)(params_R)          # (R, P_pad)
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    total = ops.hybrid_flush(rows, jnp.ones((R,), jnp.float32),
-                             use_pallas=use_pallas, interpret=interpret)
+
+    def flush(r):
+        return ops.hybrid_flush(r, jnp.ones((R,), jnp.float32),
+                                use_pallas=use_pallas, interpret=interpret)
+
+    if mesh is None:
+        total = flush(rows)
+    else:
+        # every device gets a whole number of kernel tiles of P
+        spread = P(None, mesh.axis_names)
+        rows = jnp.pad(rows, ((0, 0),
+                              (0, -rows.shape[1] % (mesh.size * TILE_P))))
+        rows = jax.lax.with_sharding_constraint(
+            rows, NamedSharding(mesh, spread))
+        total = jax.shard_map(flush, mesh=mesh, in_specs=spread,
+                              out_specs=P(mesh.axis_names),
+                              check_vma=False)(rows)
+        # one gather of the mean slab, then every device decodes it
+        # locally (slicing a P-spread slab per leaf would reshard each)
+        total = jax.lax.with_sharding_constraint(
+            total, NamedSharding(mesh, P()))[:codec.padded_size]
     mean_tree = codec.decode(total / R)
 
     def m(mean_leaf, p):
         mean_b = jnp.broadcast_to(mean_leaf[None], p.shape)
         return alpha * mean_b + (1 - alpha) * p
     return jax.tree.map(m, mean_tree, params_R)
+
+
+def rejoin_replicas(params_R, R_new: int, *, mesh: Mesh, out_shardings,
+                    alpha: float = 1.0):
+    """A phase switch on the device mesh: merge the replicas of
+    ``params_R`` (laid out over ``mesh``) with
+    :func:`merge_replicas_slab`, regroup them into ``R_new``
+    (:func:`reshard_replicas`), and place the result on
+    ``out_shardings`` (which may belong to another mesh over the same
+    devices) — one program, with no host round trip and no device
+    holding more than its share of the replicas.  One replica at
+    ``alpha=1`` is its own mean, exactly, so only the regrouping runs."""
+    if jax.tree.leaves(params_R)[0].shape[0] == 1 and alpha == 1.0:
+        merge = lambda p: p  # noqa: E731
+    else:
+        merge = functools.partial(merge_replicas_slab, alpha=alpha,
+                                  mesh=mesh)
+    return jax.jit(lambda p: reshard_replicas(merge(p), R_new),
+                   out_shardings=out_shardings)(params_R)
 
 
 def reshard_replicas(params_R, R_new: int):

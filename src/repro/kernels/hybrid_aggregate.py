@@ -33,9 +33,12 @@ def _flush_kernel(w_ref, g_ref, o_ref):
 
 
 def flush_pallas(grads: jax.Array, weights: jax.Array, *,
-                 tile_p: int = TILE_P, interpret: bool = False) -> jax.Array:
+                 out_dtype=None, tile_p: int = TILE_P,
+                 interpret: bool = False) -> jax.Array:
     """grads: (K, P) with P % tile_p == 0; weights: (K,) fp32 (normalized
-    by the caller).  Returns (P,) weighted sum in grads.dtype."""
+    by the caller).  Returns (P,) weighted sum in ``out_dtype`` (default
+    grads.dtype).  bf16 rows are upcast per tile in VMEM, so a bf16
+    staging buffer is read as-is and never widened in HBM."""
     K, P = grads.shape
     assert P % tile_p == 0, (P, tile_p)
     w2 = weights.reshape(K, 1).astype(jnp.float32)
@@ -47,7 +50,7 @@ def flush_pallas(grads: jax.Array, weights: jax.Array, *,
             pl.BlockSpec((K, tile_p), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((tile_p,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((P,), grads.dtype),
+        out_shape=jax.ShapeDtypeStruct((P,), out_dtype or grads.dtype),
         interpret=interpret,
     )(w2, grads)
 
@@ -81,9 +84,10 @@ def _flush_momentum_kernel(w_ref, beta_ref, g_ref, m_ref, o_ref, new_m_ref):
 
 def flush_momentum_pallas(grads: jax.Array, weights: jax.Array,
                           momentum: jax.Array, beta: float, *,
-                          tile_p: int = TILE_P,
+                          out_dtype=None, tile_p: int = TILE_P,
                           interpret: bool = False):
-    """Fused flush+momentum.  Returns (update, new_momentum)."""
+    """Fused flush+momentum.  Returns (update, new_momentum); the update
+    is in ``out_dtype`` (default grads.dtype)."""
     K, P = grads.shape
     assert P % tile_p == 0
     w2 = weights.reshape(K, 1).astype(jnp.float32)
@@ -102,7 +106,7 @@ def flush_momentum_pallas(grads: jax.Array, weights: jax.Array,
             pl.BlockSpec((tile_p,), lambda i: (i,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((P,), grads.dtype),
+            jax.ShapeDtypeStruct((P,), out_dtype or grads.dtype),
             jax.ShapeDtypeStruct((P,), momentum.dtype),
         ],
         interpret=interpret,

@@ -1,7 +1,8 @@
 """Process-level XLA environment knobs that must be set *before* jax is
-imported (device topology is fixed at first import).  jax-free on
-purpose: both ``repro.launch.dryrun`` (under ``__main__``) and the
-``python -m repro dryrun`` CLI call this before touching jax."""
+imported (device topology and config defaults are fixed at first
+import).  jax-free on purpose: both ``repro.launch.dryrun`` (under
+``__main__``) and the ``python -m repro`` CLI call these before touching
+jax."""
 from __future__ import annotations
 
 import os
@@ -9,6 +10,29 @@ import sys
 import warnings
 
 DRYRUN_DEVICE_COUNT = 512   # the multi-pod dry-run's forced host devices
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CACHE_DIRNAME = ".jax_cache"   # under the checkout root; git-ignored
+
+
+def use_compile_cache() -> "str | None":
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` already in the environment wins and
+    is left alone.  Otherwise, when this package runs from a source
+    checkout (``<root>/src/repro``), the variable is set to
+    ``<root>/.jax_cache``: a path that never moves, because the path is
+    part of what a cache hit matches.  Setting the environment, not a
+    jax config call, is what lets ``proc`` children and ``repro join``
+    groups inherit the same cache.  Returns the directory in effect, or
+    None (installed package, no variable set: no cache)."""
+    if os.environ.get(CACHE_ENV):
+        return os.environ[CACHE_ENV]
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    if not os.path.isdir(os.path.join(root, "src", "repro")):
+        return None
+    os.environ[CACHE_ENV] = os.path.join(root, CACHE_DIRNAME)
+    return os.environ[CACHE_ENV]
 
 
 def force_host_device_count(n: int = DRYRUN_DEVICE_COUNT) -> bool:

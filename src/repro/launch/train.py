@@ -40,9 +40,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_checkpoint
 from repro.configs.registry import ARCH_NAMES, get_config, smoke_variant
 from repro.core.spmd_hybrid import (build_phases, make_replica_step,
-                                    merge_replicas_slab, replica_divergence,
-                                    replica_param_shardings,
-                                    replicate_params, reshard_replicas)
+                                    rejoin_replicas,
+                                    replica_param_shardings)
 from repro.data.synthetic import token_stream
 from repro.launch.steps import make_train_step
 from repro.models import model as M
@@ -132,28 +131,40 @@ def run_training(spec, ckpt_dir: Optional[str] = None,
     step = 0
     steps = spec.steps
 
+    def merged(R_new: int, alpha: float = 1.0):
+        """The current phase's ``params_R`` merged on its ``mesh`` and
+        regrouped into ``R_new`` replicas on the mesh for ``R_new`` (the
+        merge runs the same fused slab flush the parameter server
+        applies)."""
+        # the last step has finished before the merge's collectives
+        # start: XLA-CPU's in-process communicator deadlocks if modules
+        # with collectives interleave
+        jax.block_until_ready(params_R)
+        return rejoin_replicas(
+            params_R, R_new, mesh=mesh, alpha=alpha,
+            out_shardings=replica_param_shardings(
+                params, build_hybrid_mesh(R_new, spec.mesh_model)))
+
     for idx, (t_start, g) in enumerate(phases):
         t_end = phases[idx + 1][0] if idx + 1 < len(phases) else steps
         R = max(1, data_axis // g)
         if params_R is None:
-            host_R = replicate_params(jax.device_get(params), R)
+            # each device receives only its shard of the broadcast
+            host = jax.device_get(params)
+            params_R = jax.device_put(
+                jax.tree.map(lambda x: np.broadcast_to(x[None],
+                                                       (R,) + x.shape),
+                             host),
+                replica_param_shardings(
+                    params, build_hybrid_mesh(R, spec.mesh_model)))
         else:
             # Phase switch (the paper's buffer flush): merge replicas and
-            # change the group factor.  Done host-side — the device arrays
-            # are fetched, merged/resharded outside the mesh, and re-placed
-            # under the new mesh.  This keeps exactly one SPMD executable
-            # alive per phase (XLA-CPU's in-process communicator deadlocks
-            # if modules with collectives interleave; on TPU this is one
-            # host-sync per phase, a handful per run).  The merge itself
-            # routes through the slab aggregation path — the same fused
-            # flush the parameter server applies.
-            host = jax.device_get(params_R)
-            host = merge_replicas_slab(host, alpha=spec.merge_alpha)
-            host_R = reshard_replicas(host, R)
+            # change the group factor, as one program on the device mesh
+            params_R = merged(R, alpha=spec.merge_alpha)
         mesh = build_hybrid_mesh(R, spec.mesh_model)
+        devices = len(set().union(*(x.sharding.device_set
+                                    for x in jax.tree.leaves(params_R))))
         with axis_rules(mesh):
-            p_sh = replica_param_shardings(params, mesh)
-            params_R = jax.device_put(host_R, p_sh)
             opt_R = jax.jit(jax.vmap(opt.init))(params_R)
             jax.block_until_ready((params_R, opt_R))
             replica_step = make_replica_step(loss_fn, opt_update)
@@ -172,6 +183,7 @@ def run_training(spec, ckpt_dir: Optional[str] = None,
                     assert int(metrics["replicas"]) == R, \
                         (int(metrics["replicas"]), R)
                     rec = {"step": step, "group_size": g, "replicas": R,
+                           "devices": devices,
                            "loss": float(metrics["loss"]),
                            "divergence": div,
                            "wall_s": round(time.time() - t0, 2),
@@ -185,15 +197,15 @@ def run_training(spec, ckpt_dir: Optional[str] = None,
 
             jax.block_until_ready((params_R, opt_R))
             if ckpt_dir:
-                merged = merge_replicas_slab(jax.device_get(params_R))
-                one = jax.tree.map(lambda x: np.asarray(x[0]), merged)
+                one = jax.tree.map(lambda x: np.asarray(x[0]),
+                                   jax.device_get(merged(1)))
                 save_checkpoint(os.path.join(ckpt_dir, f"step_{step}"),
                                 one, step, extra={"arch": spec.arch,
                                                   "mode": spec.mode})
 
     # final merge for the returned model
     params_final = jax.tree.map(lambda x: np.asarray(x[0]),
-                                merge_replicas_slab(jax.device_get(params_R)))
+                                jax.device_get(merged(1)))
     stats = {"num_updates": step, "num_gradients": grads_done}
     if out_json:
         with open(out_json, "w") as f:

@@ -196,6 +196,37 @@ def test_cluster_hybrid_more_grads_than_updates():
     _check_conservation(res)
 
 
+def test_cluster_result_records_placement():
+    """The result says where the work ran: the server's device, which
+    flush path its aggregator took, and the platform of every worker."""
+    import jax
+    res = run(_cluster_spec())
+    place = res.extra["placement"]
+    backend = jax.default_backend()
+    assert place["platform"] == backend
+    assert place["device_kind"] == jax.devices()[0].device_kind
+    # off the TPU the flush is the jnp fold, never the Pallas interpreter
+    assert place["flush"] == ("pallas" if backend == "tpu" else "jnp")
+    assert place["worker_platforms"] == {str(w): backend for w in range(3)}
+    assert res.extra["setup_s"] > 0
+
+
+@pytest.mark.parametrize("backend,warns", [("cpu", False), ("tpu", True)])
+def test_worker_processes_compute_on_cpu_and_say_so(monkeypatch, backend,
+                                                    warns):
+    """A same-host worker process always computes on the CPU; under an
+    accelerator parent that is announced, never silent."""
+    import warnings
+
+    import jax
+    from repro.cluster.mptransport import worker_process_platform
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with warnings.catch_warnings(record=True) as seen:
+        warnings.simplefilter("always")
+        assert worker_process_platform() == "cpu"
+    assert [w.category for w in seen] == ([RuntimeWarning] if warns else [])
+
+
 def test_unknown_cluster_workload():
     with pytest.raises(ValueError, match="unknown cluster workload"):
         ClusterTrainer().run(_cluster_spec(arch="resnet"))

@@ -511,6 +511,7 @@ def test_two_host_groups_bitwise_identical_to_inproc():
     trainer2 = ClusterTrainer()
     runtime = trainer2.build_runtime(spec)
     assert runtime.listen_address[1] != 0       # resolved, advertisable
+    runtime.join_platform = CHILD_PLATFORM or jax.default_backend()
     procs = [spawn_join_process(runtime.listen_address, workers=1,
                                 platform=CHILD_PLATFORM)
              for _ in range(2)]
@@ -532,6 +533,9 @@ def test_two_host_groups_bitwise_identical_to_inproc():
     a = _check_conservation(res_h)
     assert a["applied"] == 12 and res_h.num_updates == 6
     finals["host"] = trainer2.last_params
+    # the platform the leader launched its join groups on is recorded
+    assert res_h.extra["placement"]["worker_platforms"] == {
+        "0": runtime.join_platform, "1": runtime.join_platform}
 
     # the serving plane saw the run but never entered it
     serving = res_h.extra["serving"]

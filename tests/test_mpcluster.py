@@ -106,6 +106,9 @@ def test_proc_acceptance_kill_respawn_exact_ledger():
     assert kill_ev["sigkill"] is True
     assert a["computed_per_worker"]["1"] > 0
     assert a["torn_frames"] >= 0          # present, and never negative
+    # worker processes compute on the CPU, and the result says so
+    assert res.extra["placement"]["worker_platforms"] == {"0": "cpu",
+                                                          "1": "cpu"}
 
 
 def test_proc_sync_kill_respawn_barrier_keeps_moving():

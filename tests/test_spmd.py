@@ -180,3 +180,55 @@ def test_train_driver_hybrid_end_to_end():
     gs = [h["group_size"] for h in hist]
     assert gs[0] == 1 and gs[-1] == 2   # annealed to full axis
     assert all(isinstance(h["loss"], float) for h in hist)
+
+
+def test_phase_switch_merge_on_mesh_matches_host_merge():
+    """A phase switch merges on the device mesh, each device reducing
+    all replicas over its own slice of P — bit for bit what merging the
+    replicas on one device gives, in each leaf's own dtype (bf16 leaves
+    stay bf16), and placed on every device of the new mesh."""
+    out = run_py("""
+        import dataclasses
+        import jax, numpy as np
+        from repro.configs.registry import get_config, smoke_variant
+        from repro.models import model as M
+        from repro.core.spmd_hybrid import (merge_replicas_slab,
+                                            rejoin_replicas,
+                                            replica_param_shardings,
+                                            reshard_replicas)
+        from repro.launch.train import build_hybrid_mesh
+
+        base = smoke_variant(get_config("xlstm-350m"))
+        for dtype in ("float32", "bfloat16"):
+            cfg = dataclasses.replace(base, dtype=dtype)
+            params = M.init_params(jax.random.PRNGKey(0), cfg)
+            leaves, td = jax.tree.flatten(params)
+            for r_old, r_new, alpha in [(4, 2, 1.0), (4, 2, 0.5),
+                                        (2, 1, 0.5), (4, 1, 1.0),
+                                        (1, 2, 1.0)]:
+                keys = jax.random.split(jax.random.PRNGKey(r_old),
+                                        len(leaves))
+                pR = jax.tree.unflatten(td, [np.asarray(
+                    x[None] + 0.01 * jax.random.normal(
+                        k, (r_old,) + x.shape).astype(x.dtype))
+                    for x, k in zip(leaves, keys)])
+                host = reshard_replicas(merge_replicas_slab(
+                    jax.device_put(pR, jax.devices()[0]), alpha), r_new)
+                mesh = build_hybrid_mesh(r_old)
+                dev = rejoin_replicas(
+                    jax.device_put(pR, replica_param_shardings(params,
+                                                               mesh)),
+                    r_new, mesh=mesh, alpha=alpha,
+                    out_shardings=replica_param_shardings(
+                        params, build_hybrid_mesh(r_new)))
+                for x, a, b in zip(leaves, jax.tree.leaves(host),
+                                   jax.tree.leaves(dev)):
+                    assert len(b.sharding.device_set) == 4
+                    assert b.shape == a.shape
+                    assert b.dtype == a.dtype == x.dtype
+                    np.testing.assert_array_equal(
+                        np.asarray(a).view(np.uint8),
+                        np.asarray(jax.device_get(b)).view(np.uint8))
+        print("MESH_MERGE_OK")
+        """, devices=4)
+    assert "MESH_MERGE_OK" in out
