@@ -250,8 +250,14 @@ class ClusterRuntime:
         grad_fn = jax.grad(loss_fn)
 
         def _grad_slab(p_slab, x, y):
-            return self.codec.encode(
-                grad_fn(self.codec.decode(p_slab), x, y))
+            # named stages: a profile's op names say which part of the
+            # fused executable an op belongs to
+            with jax.named_scope("decode"):
+                params = self.codec.decode(p_slab)
+            with jax.named_scope("loss_grad"):
+                grads = grad_fn(params, x, y)
+            with jax.named_scope("encode"):
+                return self.codec.encode(grads)
 
         self._grad = jax.jit(_grad_slab)
         self._loss = jax.jit(loss_fn)
@@ -289,9 +295,9 @@ class ClusterRuntime:
                 slab_dtype=self.slab_dtype)
         else:
             self.transport = InProcTransport(grad_capacity=cap)
-        # hand the socket hubs the live bus (wire byte counters,
-        # grad_rx spans, the STATS push plane); InProcTransport carries
-        # no instrumentation of its own and just ignores the attribute
+        # hand the transport the live bus (the in-process queue's
+        # grad_queue_s; the socket hubs' wire byte counters, grad_rx
+        # spans and STATS push plane)
         self.transport.obs = self.obs
         # the resolved bind address (host transport): port 0 in `listen`
         # has been replaced by the real ephemeral port by now
@@ -560,7 +566,8 @@ class ClusterRuntime:
                 return
             if self._stop.is_set():
                 return
-            version, slab, _ = self.server.snapshot_slab()
+            with self.obs.span("sampler", "snapshot"):
+                version, slab, _ = self.server.snapshot_slab()
             snaps.append((target, version, slab))
             i += 1
 
@@ -660,8 +667,9 @@ class ClusterRuntime:
             wx, wy = next(shard_iterator(self.x_tr, self.y_tr, 0,
                                          self.num_workers, self.batch,
                                          seed=self.seed))
-            jax.block_until_ready(
-                self._grad(self.codec.encode(start_params), wx, wy))
+            with self.obs.span("runtime", "compile_grad"):
+                jax.block_until_ready(
+                    self._grad(self.codec.encode(start_params), wx, wy))
 
         if self.transport_kind in ("proc", "host"):
             # hold BEFORE the server's construction-time publish: a
@@ -669,15 +677,17 @@ class ClusterRuntime:
             # setting up must idle in fetch_params, not bank gradients
             # before the serving clock starts
             self.transport.hold_params()
-        self.server = ParameterServer(
-            start_params, lr=self.lr, mode=self.mode,
-            transport=self.transport, num_workers=self.num_workers,
-            schedule=self.schedule, flush_mode=self.flush_mode,
-            staleness_decay=self.staleness_decay,
-            max_gradients=self.max_gradients,
-            start_version=start_version,
-            slab_dtype=self.slab_dtype, optimizer=self.optimizer,
-            obs=self.obs)
+        # construction compiles and warms the stage + flush executables
+        with self.obs.span("runtime", "server_warmup"):
+            self.server = ParameterServer(
+                start_params, lr=self.lr, mode=self.mode,
+                transport=self.transport, num_workers=self.num_workers,
+                schedule=self.schedule, flush_mode=self.flush_mode,
+                staleness_decay=self.staleness_decay,
+                max_gradients=self.max_gradients,
+                start_version=start_version,
+                slab_dtype=self.slab_dtype, optimizer=self.optimizer,
+                obs=self.obs)
         if resume_opt_state is not None:
             # after construction (warmup rewound the count to 0) and
             # before any worker can flush: load the checkpointed
@@ -767,6 +777,7 @@ class ClusterRuntime:
 
             self._t0 = time.monotonic()
             setup_s = self._t0 - setup_t0
+            self.server.start_clock(self._t0)
             if self.transport_kind in ("proc", "host"):
                 self.transport.release_params()     # the starting gun
             if start_version:
@@ -789,19 +800,14 @@ class ClusterRuntime:
                     self._spawn(wid)
 
             deadline = self._t0 + self.wall_budget_s
-            next_q = 0.0            # queue-depth sampling grid (~5 Hz)
             while time.monotonic() < deadline \
                     and not self.server.done.is_set():
-                msg = self.transport.recv_gradient(timeout=min(
-                    0.02, max(1e-3, deadline - time.monotonic())))
+                with self.obs.span("server", "recv_wait",
+                                   hist="recv_wait_s"):
+                    msg = self.transport.recv_gradient(timeout=min(
+                        0.02, max(1e-3, deadline - time.monotonic())))
                 if msg is not None:
                     self.server.ingest(msg)
-                now = time.monotonic() - self._t0
-                if now >= next_q:
-                    self.obs.observe(
-                        "queue_depth",
-                        self.transport.pending_gradients())
-                    next_q = now + 0.2
             wall_s = self._elapsed()
         finally:
             # ---------------------------------------------- shutdown
@@ -872,14 +878,16 @@ class ClusterRuntime:
 
         # ---------------------------------- evaluate the metric snapshots
         times, tr, te, acc = [], [], [], []
-        for target, _, slab in snaps:
-            params = self.codec.decode(slab)
-            times.append(target)
-            tr.append(float(self._loss(params, self.x_tr[:2048],
-                                       self.y_tr[:2048])))
-            te.append(float(self._loss(params, self.x_te, self.y_te)))
-            acc.append(float(self._acc(params, self.x_te, self.y_te))
-                       if self._acc is not None else 0.0)
+        with self.obs.span("runtime", "eval", snapshots=len(snaps)):
+            for target, _, slab in snaps:
+                params = self.codec.decode(slab)
+                times.append(target)
+                tr.append(float(self._loss(params, self.x_tr[:2048],
+                                           self.y_tr[:2048])))
+                te.append(float(self._loss(params, self.x_te,
+                                           self.y_te)))
+                acc.append(float(self._acc(params, self.x_te, self.y_te))
+                           if self._acc is not None else 0.0)
 
         # snapshot() already returns a host copy (the donation rule:
         # nothing escaping the server may alias the donated slab)

@@ -121,6 +121,10 @@ class ParameterServer:
         self.live: Set[int] = set()
         self._round: Dict[int, Any] = {}    # sync: worker_id -> grad slab
         self.done = threading.Event()       # max_gradients budget reached
+        # time of the last publish of an update, or of the window's
+        # start before the first (publish_gap_s); None until
+        # start_clock, so set-up's publishes are never counted
+        self._last_publish_t: Optional[float] = None
         transport.publish_params(ParamsMsg(self.version,
                                            self.agg.params_slab))
 
@@ -160,9 +164,18 @@ class ParameterServer:
                 # a shrinking fleet may complete the round it was blocking
                 self._maybe_complete_round()
 
+    def start_clock(self, t: float) -> None:
+        """The serving window opened at ``t`` (``time.monotonic()``):
+        the first ``publish_gap_s`` sample runs from here."""
+        with self.lock:
+            self._last_publish_t = t
+
     # ---------------------------------------------------------- ingest
     def ingest(self, msg: GradientMsg) -> None:
-        with self.lock:
+        # the span covers the wait for the lock too (a snapshot or a
+        # membership change holds it)
+        with self.obs.span("server", "ingest", worker=msg.worker_id,
+                           seq=msg.seq, version=msg.version), self.lock:
             # telemetry: every gradient that reached the server, and
             # how stale it was on arrival (server version minus the
             # version it was computed against; negative after a restore
@@ -170,9 +183,7 @@ class ParameterServer:
             # grads_ingested == applied + dropped + buffered + pending
             self.obs.count("grads_ingested")
             self.obs.count(f"grads_ingested.w{msg.worker_id}")
-            stale = self.version - msg.version
-            self.obs.observe("staleness", stale)
-            self.obs.observe(f"staleness.w{msg.worker_id}", stale)
+            self.obs.observe("staleness", self.version - msg.version)
             if self.done.is_set():
                 self.dropped += 1
                 self.obs.count("drops.budget")
@@ -201,15 +212,17 @@ class ParameterServer:
         if not self.live or not set(self._round) >= self.live:
             return
         wids = sorted(self._round)          # deterministic fold order
-        for slot, w in enumerate(wids):
-            self.agg.stage(self._round[w], slot)
+        with self.obs.span("server", "stage_dispatch", k=len(wids)):
+            for slot, w in enumerate(wids):
+                self.agg.stage(self._round[w], slot)
         k = len(wids)
         self._round = {}
         # sync: the plain mean of the round's gradients
         self._apply(np.ones((k,)), self.lr)
 
     def _ingest_buffered(self, msg: GradientMsg) -> None:
-        self.buffer.add(msg.grad, msg.version)
+        with self.obs.span("server", "stage_dispatch", k=1):
+            self.buffer.add(msg.grad, msg.version)
         # async is K ≡ 1 by definition (its one-row staging buffer
         # depends on it); hybrid asks the K(t) schedule
         k_needed = 1 if self.mode == "async" else \
@@ -232,30 +245,28 @@ class ParameterServer:
             self._apply(weights, scale)
 
     def _apply(self, weights: np.ndarray, scale: float) -> None:
-        t0 = time.monotonic()
-        pub = self.agg.flush_apply(weights, scale)
-        dt = time.monotonic() - t0
+        # host dispatch of the fused flush + optimizer step: the device
+        # time is in a profile of the run (the span's annotation puts
+        # it on the same clock), not in this histogram
+        with self.obs.span("server", "flush_dispatch",
+                           hist="flush_dispatch_s", k=len(weights),
+                           version=self.version + 1):
+            pub = self.agg.flush_apply(weights, scale)
         self.version += 1
         self.updates_applied += 1
         self.applied += len(weights)
-        self.obs.observe("flush_s", dt)
-        # the optimizer step IS the fused flush — one histogram + one
-        # counter at the seam, whatever the optimizer (sgd included),
-        # so `repro top`/Prometheus can watch update latency per choice
-        self.obs.observe("opt_update_s", dt)
         self.obs.count("optimizer_steps")
-        self.obs.span_at("server", "flush", t0, dt, k=len(weights),
-                         version=self.version)
         self.obs.count("grads_applied", len(weights))
         self.obs.count("updates")
-        t1 = time.monotonic()
-        self.transport.publish_params(
-            ParamsMsg(self.version, pub, epoch=self.restore_epoch))
-        dt1 = time.monotonic() - t1
-        self.obs.observe("publish_s", dt1)
-        self.obs.span_at("server", "publish", t1, dt1,
-                         version=self.version)
+        with self.obs.span("server", "publish", hist="publish_s",
+                           version=self.version):
+            self.transport.publish_params(
+                ParamsMsg(self.version, pub, epoch=self.restore_epoch))
         self.obs.count("params_published")
+        if self._last_publish_t is not None:
+            now = time.monotonic()
+            self.obs.observe("publish_gap_s", now - self._last_publish_t)
+            self._last_publish_t = now
         if self.max_gradients and self.applied >= self.max_gradients:
             self.done.set()
 

@@ -38,7 +38,10 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from typing import Any, Optional, Protocol
+
+from repro.obs.telemetry import NULL
 
 # the spec-facing transport names (ExperimentSpec.transport / --transport):
 #   inproc — worker threads + queue: one address space, GIL-shared compute
@@ -134,17 +137,31 @@ class Transport(Protocol):
         ...
 
 
+class _StampedQueue(queue.Queue):
+    """A queue whose items carry the ``time.monotonic()`` at which they
+    entered it: ``_put`` runs under the queue's mutex once there is
+    room, so the stamp excludes a blocked sender's backpressure wait."""
+
+    def _put(self, item) -> None:
+        self.queue.append((time.monotonic(), item))
+
+
 class InProcTransport:
     """Threads-in-one-process transport: queue + versioned broadcast cell.
 
     ``grad_capacity`` bounds the gradient queue (0 = unbounded): a full
     queue blocks the sending worker, which is the backpressure a real
     wire applies when the server is the bottleneck — without it an
-    outpaced server accumulates an unbounded stale-gradient backlog."""
+    outpaced server accumulates an unbounded stale-gradient backlog.
+
+    Every gradient taken is observed into ``grad_queue_s`` on ``obs``
+    (set by the runtime): the time it sat in the queue."""
+
+    obs = NULL
 
     def __init__(self, grad_capacity: int = 0):
-        self._grads: "queue.Queue[GradientMsg]" = \
-            queue.Queue(maxsize=grad_capacity)
+        self._grads: "queue.Queue[tuple]" = \
+            _StampedQueue(maxsize=grad_capacity)
         self._cell: Optional[ParamsMsg] = None
         self._cond = threading.Condition()
 
@@ -166,10 +183,13 @@ class InProcTransport:
         # mean get_nowait(), the opposite of the send side's contract
         try:
             if timeout is not None and timeout <= 0:
-                return self._grads.get_nowait()
-            return self._grads.get(timeout=timeout)
+                t_put, msg = self._grads.get_nowait()
+            else:
+                t_put, msg = self._grads.get(timeout=timeout)
         except queue.Empty:
             return None
+        self.obs.observe("grad_queue_s", time.monotonic() - t_put)
+        return msg
 
     def pending_gradients(self) -> int:
         return self._grads.qsize()      # exact once producers stopped

@@ -35,7 +35,6 @@ Every transport wait here is a short *positive* timeout (never ``None``
 from __future__ import annotations
 
 import threading
-import time
 import traceback
 from typing import Callable, Iterator, Optional
 
@@ -73,10 +72,12 @@ class Worker(threading.Thread):
     def _loop(self) -> None:
         next_version = 0        # sync: the round we haven't contributed to
         epoch = 0               # restore epoch of the params last used
+        track = f"worker/{self.worker_id}"
         while not self.stop_event.is_set():
             min_v = next_version if self.mode == "sync" else 0
-            msg = self.transport.fetch_params(min_version=min_v,
-                                              timeout=0.05)
+            with self.obs.span(track, "fetch_wait"):
+                msg = self.transport.fetch_params(min_version=min_v,
+                                                  timeout=0.05)
             if msg is None:
                 if self.mode == "sync" and min_v > 0:
                     # a checkpoint restore moves the server's version
@@ -96,28 +97,26 @@ class Worker(threading.Thread):
                     continue
             epoch = getattr(msg, "epoch", 0)
             x, y = next(self.batches)
-            t0 = time.monotonic()
-            grad = self.grad_fn(msg.params, x, y)
-            jax.block_until_ready(grad)
-            dt = time.monotonic() - t0
-            self.obs.observe("grad_s", dt)
-            self.obs.observe(f"grad_s.w{self.worker_id}", dt)
-            self.obs.span_at(f"worker/{self.worker_id}", "grad_compute",
-                             t0, dt, version=msg.version)
+            seq = self.sent + 1
+            # blocks on the device: the span is the gradient's compute
+            with self.obs.span(track, "grad_compute", hist="grad_s",
+                               worker=self.worker_id, seq=seq,
+                               version=msg.version):
+                grad = self.grad_fn(msg.params, x, y)
+                jax.block_until_ready(grad)
             if self.straggle_s and self.stop_event.wait(self.straggle_s):
                 break           # killed mid-straggle: gradient is lost
-            out = GradientMsg(self.worker_id, grad, msg.version,
-                              self.sent + 1)
-            t0 = time.monotonic()
-            ok = False          # bounded queue: block until the server
-            while not ok and not self.stop_event.is_set():  # drains, or
-                ok = self.transport.send_gradient(out, timeout=0.05)
+            out = GradientMsg(self.worker_id, grad, msg.version, seq)
+            # bounded queue: block until the server drains, or until
+            # killed while blocked (the gradient is then lost)
+            ok = False
+            with self.obs.span(track, "send_wait", hist="send_wait_s",
+                               worker=self.worker_id, seq=seq,
+                               version=msg.version):
+                while not ok and not self.stop_event.is_set():
+                    ok = self.transport.send_gradient(out, timeout=0.05)
             if not ok:
-                break           # ...killed while blocked: gradient lost
-            wait = time.monotonic() - t0
-            self.obs.observe("send_wait_s", wait)
-            self.obs.span_at(f"worker/{self.worker_id}", "send_wait",
-                             t0, wait, version=msg.version)
+                break
             self.sent += 1
             if self.mode == "sync":
                 next_version = msg.version + 1

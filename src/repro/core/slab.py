@@ -357,9 +357,11 @@ class SlabAggregator:
         # donated: an in-place row write, not a buffer copy.  The cast
         # is a trace-time no-op when the row already arrives in the
         # staging dtype (the native-wire case)
-        if row.dtype != staging.dtype:
-            row = row.astype(staging.dtype)
-        return jax.lax.dynamic_update_slice(staging, row[None], (slot, 0))
+        with jax.named_scope("stage"):
+            if row.dtype != staging.dtype:
+                row = row.astype(staging.dtype)
+            return jax.lax.dynamic_update_slice(staging, row[None],
+                                                (slot, 0))
 
     def _flush_impl(self, pslab, staging, weights, scale):
         # both branches reduce via zero-weight masking — rows past the
@@ -369,21 +371,25 @@ class SlabAggregator:
         # per tile (widening the staging buffer in HBM first would cost
         # K_max·P·4 bytes of scratch), the jnp fold upcasts here (for
         # f32 rows the cast disappears at trace time)
-        if self.use_pallas:
-            agg = flush_pallas(staging, weights, out_dtype=jnp.float32,
-                               interpret=self.interpret)
-        else:
-            # jnp fallback: a statically unrolled masked fold in staging
-            # order — structurally identical to the legacy per-leaf fold
-            # (same muls, same adds, same order), which keeps the sync
-            # round mean bitwise-equal to the pre-slab server.  (A
-            # fori_loop over only the k live rows compiles to different
-            # FMA contraction and drifts by 1 ulp.)
-            rows = staging.astype(jnp.float32)
-            agg = weights[0] * rows[0]
-            for i in range(1, self.k_max):
-                agg = agg + weights[i] * rows[i]
-        new = pslab - scale * (agg / jnp.sum(weights))
+        with jax.named_scope("aggregate"):
+            if self.use_pallas:
+                agg = flush_pallas(staging, weights,
+                                   out_dtype=jnp.float32,
+                                   interpret=self.interpret)
+            else:
+                # jnp fallback: a statically unrolled masked fold in
+                # staging order — structurally identical to the legacy
+                # per-leaf fold (same muls, same adds, same order),
+                # which keeps the sync round mean bitwise-equal to the
+                # pre-slab server.  (A fori_loop over only the k live
+                # rows compiles to different FMA contraction and drifts
+                # by 1 ulp.)
+                rows = staging.astype(jnp.float32)
+                agg = weights[0] * rows[0]
+                for i in range(1, self.k_max):
+                    agg = agg + weights[i] * rows[i]
+        with jax.named_scope("apply"):
+            new = pslab - scale * (agg / jnp.sum(weights))
         # the second output is the published copy: a fresh buffer that
         # does NOT alias the donated input (tests/test_slab.py guards
         # this against XLA deciding to alias the two outputs)
@@ -393,9 +399,10 @@ class SlabAggregator:
         """The publish copy of a freshly updated master slab: a fresh
         buffer that never aliases the donated master (in bf16 mode the
         publish IS the narrowing cast)."""
-        if self.codec.slab_dtype == jnp.dtype(jnp.float32):
-            return new + 0.0
-        return new.astype(self.codec.slab_dtype)
+        with jax.named_scope("publish_cast"):
+            if self.codec.slab_dtype == jnp.dtype(jnp.float32):
+                return new + 0.0
+            return new.astype(self.codec.slab_dtype)
 
     def _mean_grad(self, staging, weights):
         """The flush's weighted-mean gradient on the jnp path: the same
@@ -413,17 +420,22 @@ class SlabAggregator:
         # params' = params - scale·mu'.  ``pslab`` and ``mu`` are
         # donated; the moments stay f32 whatever the staging dtype
         if self.use_pallas:
-            upd, mu_new = flush_momentum_pallas(
-                staging, weights / jnp.sum(weights), mu, self.opt.beta1,
-                out_dtype=jnp.float32, interpret=self.interpret)
-            new = pslab - scale * upd
-            count_new = count + 1
+            with jax.named_scope("aggregate"):
+                upd, mu_new = flush_momentum_pallas(
+                    staging, weights / jnp.sum(weights), mu,
+                    self.opt.beta1, out_dtype=jnp.float32,
+                    interpret=self.interpret)
+            with jax.named_scope("apply"):
+                new = pslab - scale * upd
+                count_new = count + 1
         else:
-            g = self._mean_grad(staging, weights)
-            upd, st = self._pair.update(
-                g, {"count": count, "mu": mu}, pslab)
-            new = pslab + scale * upd
-            mu_new, count_new = st["mu"], st["count"]
+            with jax.named_scope("aggregate"):
+                g = self._mean_grad(staging, weights)
+            with jax.named_scope("apply"):
+                upd, st = self._pair.update(
+                    g, {"count": count, "mu": mu}, pslab)
+                new = pslab + scale * upd
+                mu_new, count_new = st["mu"], st["count"]
         return new, mu_new, count_new, self._published(new)
 
     def _flush_adamw_impl(self, pslab, mu, nu, count, staging, weights,
@@ -432,21 +444,28 @@ class SlabAggregator:
         # count carried in state (the shared step-count convention of
         # repro.optim).  ``pslab``/``mu``/``nu`` are donated
         if self.use_pallas:
-            c = count + 1
-            bc1, bc2 = bias_correction(c, self.opt.beta1, self.opt.beta2)
-            new, mu_new, nu_new = flush_adamw_pallas(
-                staging, weights / jnp.sum(weights), pslab, mu, nu,
-                bc1, bc2, scale, b1=self.opt.beta1, b2=self.opt.beta2,
-                eps=self.opt.eps, weight_decay=self.opt.weight_decay,
-                interpret=self.interpret)
-            count_new = c
+            # one kernel aggregates and applies: it is named for the
+            # update it emits
+            with jax.named_scope("apply"):
+                c = count + 1
+                bc1, bc2 = bias_correction(c, self.opt.beta1,
+                                           self.opt.beta2)
+                new, mu_new, nu_new = flush_adamw_pallas(
+                    staging, weights / jnp.sum(weights), pslab, mu, nu,
+                    bc1, bc2, scale, b1=self.opt.beta1,
+                    b2=self.opt.beta2, eps=self.opt.eps,
+                    weight_decay=self.opt.weight_decay,
+                    interpret=self.interpret)
+                count_new = c
         else:
-            g = self._mean_grad(staging, weights)
-            upd, st = self._pair.update(
-                g, {"count": count, "mu": mu, "nu": nu}, pslab)
-            new = pslab + scale * upd
-            mu_new, nu_new = st["mu"], st["nu"]
-            count_new = st["count"]
+            with jax.named_scope("aggregate"):
+                g = self._mean_grad(staging, weights)
+            with jax.named_scope("apply"):
+                upd, st = self._pair.update(
+                    g, {"count": count, "mu": mu, "nu": nu}, pslab)
+                new = pslab + scale * upd
+                mu_new, nu_new = st["mu"], st["nu"]
+                count_new = st["count"]
         return new, mu_new, nu_new, count_new, self._published(new)
 
     # ----------------------------------------------------------- chunks

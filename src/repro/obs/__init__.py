@@ -1,17 +1,20 @@
 """``repro.obs`` — the telemetry plane.
 
 One lock-cheap in-process event bus (:class:`~repro.obs.telemetry.
-Telemetry`: counters / gauges / histograms always on, ring-buffered
-monotonic-clock spans when tracing) threaded through the cluster
-runtime, the parameter server, the workers, and the socket hubs, plus
-three export surfaces:
+Telemetry`: counters / gauges / histograms always on, spans that are
+always ``jax.profiler`` annotations and are ring-buffered when
+tracing) threaded through the cluster runtime, the parameter server,
+the workers, the in-process transport and the socket hubs, plus three
+export surfaces:
 
   * :func:`~repro.obs.trace.write_chrome_trace` — Chrome
     trace-event / Perfetto JSON (``--trace out.json`` /
-    ``python -m repro trace``), one track per worker / server / wire;
+    ``python -m repro trace``), one track per server / runtime /
+    sampler / worker / wire, on the profiler's clock;
   * ``RunResult.extra["telemetry"]`` — the structured metrics summary
-    (per-worker staleness histograms, wire bytes, queue depths, flush
-    latency percentiles) cross-checked against the conservation ledger;
+    (staleness, wire bytes, flush-dispatch / publish / publish-gap /
+    queue-wait / ingest-wait percentiles) cross-checked against the
+    conservation ledger;
   * the read-only ``STATS`` wire frame + :mod:`repro.obs.top`
     (``python -m repro top HOST:PORT``) — live remote introspection of
     a running ``--listen`` leader, riding the serve-peer admission

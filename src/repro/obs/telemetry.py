@@ -1,17 +1,12 @@
 """The in-process telemetry bus: counters, histograms, trace spans.
 
 One :class:`Telemetry` instance rides a cluster run (created by the
-runtime, shared with the server, the thread workers, and the socket
-hub).  The design constraint is the hot path: ``ingest`` and the hub
-reader threads call into this on *every gradient*, so every operation
-is a dict update under one lock — no allocation beyond the first use
-of a name, no formatting, no I/O.  Spans (for the Chrome trace export)
-are only recorded when ``trace=True``; with tracing off, ``span()``
-returns a shared no-op context manager and ``span_at``/``instant``
-return immediately, so a tracing-disabled run does the same arithmetic
-in the same order as one with the bus absent entirely — which is what
-keeps sync runs bitwise-identical with tracing on or off
-(regression-tested in ``tests/test_obs.py``).
+runtime, shared with the server, the thread workers, the in-process
+transport and the socket hub).  The design constraint is the hot path:
+``ingest`` and the hub reader threads call into this on *every
+gradient*, so every metric is a dict update under one lock — no
+allocation beyond the first use of a name, no I/O — and a span adds one
+small object and a profiler annotation, a few microseconds.
 
 Vocabulary:
 
@@ -19,13 +14,26 @@ Vocabulary:
     ``wire.rx_bytes``, ...);
   * ``gauge(name, v)`` — last-write-wins instantaneous values;
   * ``observe(name, v)`` — histogram samples (``staleness``,
-    ``flush_s``, ``queue_depth``): running count/min/max/sum plus a
-    capped sample buffer for percentiles;
-  * ``span(track, name, **args)`` / ``span_at(...)`` /
-    ``instant(...)`` — timeline events on a named track
-    (``server``, ``worker/3``, ``worker/3/wire``), monotonic-clock
-    relative to the bus's creation, exported by
-    :mod:`repro.obs.trace`.
+    ``publish_gap_s``, ``grad_queue_s``): running count/min/max/sum
+    plus a capped sample buffer for percentiles;
+  * ``span(track, name, hist=..., **args)`` — a context manager held
+    open around the work on a named track (``server``, ``worker/3``,
+    ``worker/3/wire``, ``runtime``, ``sampler``).  It always enters a
+    ``jax.profiler.TraceAnnotation`` named ``<track>/<name>`` (a no-op
+    unless the profiler is recording, so any device profile shows
+    the program's spans on its host plane) and feeds its duration to
+    the ``hist`` histogram if one is named; when ``trace=True`` it also
+    lands in the ring buffer that :mod:`repro.obs.trace` exports;
+  * ``instant(track, name, **args)`` — a zero-duration marker (K(t)
+    switch, kill, restore), ring buffer only.
+
+Spans are timed on ``time.monotonic()`` relative to the bus's creation;
+the bus records one anchor pair there — monotonic time and the wall
+clock in nanoseconds, which is the profiler's time base — so the Chrome
+export lands on the profiler's clock and overlays a device trace of the
+same run.  Tracing records spans only; it never touches the math, which
+keeps sync runs bitwise-identical with tracing on or off
+(regression-tested in ``tests/test_obs.py``).
 
 :data:`NULL` is the no-op singleton: components take ``obs=None`` and
 fall back to it, so instrumentation is zero-cost for callers that
@@ -37,6 +45,8 @@ import collections
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # spans are ring-buffered: a long run keeps the most recent window
 # rather than growing without bound (200k spans ~ tens of MB of JSON,
@@ -81,29 +91,41 @@ class _Hist:
         return {"count": self.count,
                 "min": float(self.vmin), "max": float(self.vmax),
                 "mean": self.total / self.count,
-                "p50": pct(0.50), "p99": pct(0.99)}
+                "p50": pct(0.50), "p95": pct(0.95), "p99": pct(0.99)}
 
 
 class _SpanCtx:
-    """Context manager recording one completed span on exit."""
-    __slots__ = ("_tel", "_track", "_name", "_args", "_t0")
+    """Context manager around one unit of work: a profiler annotation
+    while open; on exit, its duration to ``hist`` (if named) and, when
+    tracing, one completed span to the ring buffer."""
+    __slots__ = ("_tel", "_track", "_name", "_hist", "_args", "_ann",
+                 "_t0")
 
     def __init__(self, tel: "Telemetry", track: str, name: str,
-                 args: Optional[Dict[str, Any]]):
+                 hist: Optional[str], args: Optional[Dict[str, Any]]):
         self._tel = tel
         self._track = track
         self._name = name
+        self._hist = hist
         self._args = args
 
     def __enter__(self) -> "_SpanCtx":
+        self._ann = TraceAnnotation(f"{self._track}/{self._name}",
+                                    **(self._args or {}))
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.monotonic()
-        self._tel._spans.append(
-            ("X", self._track, self._name,
-             self._t0 - self._tel.t0, t1 - self._t0, self._args))
+        self._ann.__exit__(*exc)
+        tel = self._tel
+        if self._hist is not None:
+            tel.observe(self._hist, t1 - self._t0)
+        if tel.trace:
+            tel._spans.append(
+                ("X", self._track, self._name,
+                 self._t0 - tel.t0, t1 - self._t0, self._args))
 
 
 class _NullSpan:
@@ -125,7 +147,10 @@ class Telemetry:
 
     def __init__(self, trace: bool = False):
         self.trace = bool(trace)
-        self.t0 = time.monotonic()      # span/instant time base
+        # the anchor pair: span/instant time base, and the same instant
+        # on the profiler's clock (the wall clock, in ns)
+        self.t0 = time.monotonic()
+        self.t0_wall_ns = time.time_ns()
         self._lock = threading.Lock()
         self._counters: Dict[str, int] = {}
         self._gauges: Dict[str, float] = {}
@@ -155,22 +180,14 @@ class Telemetry:
             h.add(float(value))
 
     # ----------------------------------------------------------- timeline
-    def span(self, track: str, name: str, **args) -> Any:
-        """``with obs.span("worker/0", "grad_compute", version=v): ...``
-        — records a complete span when tracing, a shared no-op
-        otherwise."""
-        if not self.trace:
-            return _NULL_SPAN
-        return _SpanCtx(self, track, name, args or None)
-
-    def span_at(self, track: str, name: str, t_start: float,
-                dur_s: float, **args) -> None:
-        """Record an already-measured span (``t_start`` from
-        ``time.monotonic()``) — for call sites that time the work
-        anyway and feed the duration to a histogram too."""
-        if self.trace:
-            self._spans.append(("X", track, name, t_start - self.t0,
-                                dur_s, args or None))
+    def span(self, track: str, name: str, hist: Optional[str] = None,
+             **args) -> Any:
+        """``with obs.span("worker/0", "grad_compute", hist="grad_s",
+        version=v): ...`` — a profiler annotation around the block, its
+        duration observed into ``hist``, and a ring-buffered span when
+        tracing.  ``args`` (ints: worker, seq, version, ...) ride on
+        both the annotation and the buffered span."""
+        return _SpanCtx(self, track, name, hist, args or None)
 
     def instant(self, track: str, name: str, **args) -> None:
         """A zero-duration timeline marker (K(t) switch, kill,
@@ -229,12 +246,9 @@ class NullTelemetry:
     def observe(self, name: str, value: float) -> None:
         pass
 
-    def span(self, track: str, name: str, **args) -> Any:
+    def span(self, track: str, name: str, hist: Optional[str] = None,
+             **args) -> Any:
         return _NULL_SPAN
-
-    def span_at(self, track: str, name: str, t_start: float,
-                dur_s: float, **args) -> None:
-        pass
 
     def instant(self, track: str, name: str, **args) -> None:
         pass

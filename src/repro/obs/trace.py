@@ -5,11 +5,16 @@ the Chrome trace-event JSON format (the ``traceEvents`` array of
 ``ph: "X"`` complete events and ``ph: "i"`` instants, microsecond
 timestamps) that ``chrome://tracing`` and https://ui.perfetto.dev load
 directly.  Every telemetry *track* becomes one named thread row —
-``server`` first, then ``worker/0``, ``worker/0/wire``, ... — so an
-async→sync K(t) run reads as a timeline: per-worker ``grad_compute``
-spans interleaving with the server's ``flush``/``publish`` spans, wire
-``grad_rx`` spans showing backpressure waits, and instant markers for
-K(t) switches, kills, and restores.
+``server`` first, then ``runtime``, ``sampler``, ``worker/0``,
+``worker/0/wire``, ... — so an async→sync K(t) run reads as a timeline:
+per-worker ``fetch_wait``/``grad_compute``/``send_wait`` spans
+interleaving with the server's ``recv_wait``/``ingest``/``publish``
+spans, wire ``grad_rx`` spans showing backpressure waits, and instant
+markers for K(t) switches, kills, and restores.
+
+Timestamps are on the profiler's clock (wall-clock microseconds, from
+the bus's anchor pair), so the export overlays a ``jax.profiler`` trace
+of the same run, whose host plane carries the same spans by name.
 
 Produced by ``python -m repro run --backend cluster --trace out.json``
 (or the ``python -m repro trace out.json ...`` sugar).
@@ -32,10 +37,11 @@ def chrome_trace(tel) -> Dict[str, Any]:
     events += [
         {"name": "thread_sort_index", "ph": "M", "pid": 1,
          "tid": tid[t], "args": {"sort_index": tid[t]}} for t in tracks]
+    t0_us = tel.t0_wall_ns / 1e3
     for kind, track, name, t_rel, dur, args in spans:
         ev: Dict[str, Any] = {
             "name": name, "pid": 1, "tid": tid[track],
-            "ts": round(t_rel * 1e6, 3),
+            "ts": round(t0_us + t_rel * 1e6, 3),
             "cat": track.split("/", 1)[0],
         }
         if kind == "X":

@@ -211,4 +211,4 @@ def test_proc_adamw_sigkill_restore_exact_ledger_no_nan_moments(tmp_path):
     # the telemetry seam: one optimizer step per fused flush, exactly
     tel = res.extra["telemetry"]
     assert tel["counters"]["optimizer_steps"] == a["updates"]
-    assert tel["histograms"]["opt_update_s"]["count"] == a["updates"]
+    assert tel["histograms"]["flush_dispatch_s"]["count"] == a["updates"]

@@ -87,7 +87,7 @@ def test_telemetry_counters_gauges_histograms():
     assert tel.counters() == {"grads": 5, "bytes": 100}
     st = tel.hist_stats("staleness")
     assert st["count"] == 100 and st["min"] == 0.0 and st["max"] == 99.0
-    assert st["p50"] == 50.0 and st["p99"] == 98.0
+    assert st["p50"] == 50.0 and st["p95"] == 94.0 and st["p99"] == 98.0
     assert tel.hist_stats("nope") is None
     s = tel.summary()
     assert s["trace"] is False and s["spans_recorded"] == 0
@@ -97,17 +97,23 @@ def test_telemetry_counters_gauges_histograms():
 
 
 def test_spans_recorded_only_when_tracing():
+    # the span's histogram is always fed; the ring buffer only when
+    # tracing
     off = Telemetry(trace=False)
-    with off.span("server", "flush", k=3):
-        pass
-    off.span_at("server", "flush", time.monotonic(), 0.001)
+    with off.span("server", "flush_dispatch", hist="flush_dispatch_s",
+                  k=3):
+        time.sleep(0.002)
     off.instant("server", "k_switch", k=1)
     assert off.spans() == []
+    st = off.hist_stats("flush_dispatch_s")
+    assert st["count"] == 1 and st["min"] >= 0.002
 
     on = Telemetry(trace=True)
     with on.span("worker/0", "grad_compute", version=7):
         pass
-    on.span_at("server", "flush", time.monotonic(), 0.002, k=2)
+    with on.span("server", "flush_dispatch", hist="flush_dispatch_s",
+                 k=2):
+        pass
     on.instant("server", "k_switch", k=1)
     spans = on.spans()
     assert len(spans) == 3
@@ -116,6 +122,8 @@ def test_spans_recorded_only_when_tracing():
     x = next(s for s in spans if s[2] == "grad_compute")
     assert x[1] == "worker/0" and x[5] == {"version": 7}
     assert on.summary()["spans_recorded"] == 3
+    assert on.hist_stats("flush_dispatch_s")["count"] == 1
+    assert on.hist_stats("grad_compute") is None    # no hist named
 
 
 def test_null_telemetry_is_inert():
@@ -123,9 +131,8 @@ def test_null_telemetry_is_inert():
     NULL.count("x")
     NULL.gauge("x", 1.0)
     NULL.observe("x", 1.0)
-    with NULL.span("t", "n"):
+    with NULL.span("t", "n", hist="h", k=1):
         pass
-    NULL.span_at("t", "n", 0.0, 0.0)
     NULL.instant("t", "n")
     assert NULL.counters() == {} and NULL.spans() == []
     assert NULL.hist_stats("x") is None
@@ -136,9 +143,10 @@ def test_null_telemetry_is_inert():
 
 def test_chrome_trace_export(tmp_path):
     tel = Telemetry(trace=True)
-    t = time.monotonic()
-    tel.span_at("worker/1", "grad_compute", t, 0.003, version=5)
-    tel.span_at("server", "flush", t + 0.003, 0.001, k=2)
+    with tel.span("worker/1", "grad_compute", version=5):
+        time.sleep(0.003)
+    with tel.span("server", "flush_dispatch", k=2):
+        time.sleep(0.001)
     tel.instant("server", "k_switch", k=1)
     doc = chrome_trace(tel)
     events = doc["traceEvents"]
@@ -146,15 +154,24 @@ def test_chrome_trace_export(tmp_path):
     meta = {e["args"]["name"]: e["tid"] for e in events
             if e["ph"] == "M" and e["name"] == "thread_name"}
     assert meta["server"] == 0 and meta["worker/1"] == 1
-    flush = next(e for e in events if e["name"] == "flush")
-    assert flush["ph"] == "X" and flush["dur"] == pytest.approx(1000.0)
+    recorded = {s[2]: s for s in tel.spans()}
+    flush = next(e for e in events if e["name"] == "flush_dispatch")
+    assert flush["ph"] == "X" and flush["dur"] >= 1000.0
+    assert flush["dur"] == pytest.approx(recorded["flush_dispatch"][4]
+                                         * 1e6, abs=1e-3)
     assert flush["args"] == {"k": 2} and flush["cat"] == "server"
     grad = next(e for e in events if e["name"] == "grad_compute")
     assert grad["tid"] == 1 and grad["cat"] == "worker"
+    assert grad["dur"] >= 3000.0
     inst = next(e for e in events if e["name"] == "k_switch")
     assert inst["ph"] == "i" and inst["s"] == "t"
-    # X events carry microsecond timestamps relative to the bus epoch
-    assert flush["ts"] - grad["ts"] == pytest.approx(3000.0)
+    # X events carry microsecond timestamps on the profiler's clock:
+    # the bus's wall-clock anchor plus the span's monotonic offset
+    for e, name in ((flush, "flush_dispatch"), (grad, "grad_compute")):
+        assert e["ts"] == pytest.approx(
+            tel.t0_wall_ns / 1e3 + recorded[name][3] * 1e6, abs=1e-3)
+    assert flush["ts"] >= grad["ts"] + grad["dur"]
+    assert abs(grad["ts"] - time.time_ns() / 1e3) < 10e6
 
     out = tmp_path / "trace.json"
     n = write_chrome_trace(tel, str(out))
@@ -164,6 +181,109 @@ def test_chrome_trace_export(tmp_path):
     assert len(loaded["traceEvents"]) == len(events)
 
 
+def test_spans_land_on_the_profiler_host_plane(tmp_path):
+    """A span is a profiler annotation: inside a profile it appears on
+    the ``/host:`` plane as ``<track>/<name>`` with its args, and the
+    Chrome export's ``ts`` for it lies within 1 ms of that event's
+    start (the profiler's starts are relative to the profile's
+    ``profile_start_time``, a wall-clock ns stamp)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tel = Telemetry(trace=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("server", "recv_wait", hist="recv_wait_s",
+                      worker=1, seq=3):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    start = None
+    hits = []
+    for plane in pd.planes:
+        stats = dict(plane.stats)
+        if "profile_start_time" in stats:
+            start = stats["profile_start_time"]
+        if plane.name.startswith("/host:"):
+            hits += [ev for line in plane.lines for ev in line.events
+                     if ev.name == "server/recv_wait"]
+    assert start is not None and len(hits) == 1
+    ev = hits[0]
+    assert dict(ev.stats) == {"worker": 1, "seq": 3}
+    assert ev.duration_ns >= 5e6
+    exported = next(e for e in chrome_trace(tel)["traceEvents"]
+                    if e["name"] == "recv_wait")
+    assert abs(exported["ts"] - (start + ev.start_ns) / 1e3) < 1000.0
+    assert tel.hist_stats("recv_wait_s")["count"] == 1
+
+
+# ------------------------------------- named stages in the executables
+
+def _op_names(compiled) -> set:
+    import re
+    return {part for name in re.findall(r'op_name="([^"]*)"',
+                                        compiled.as_text())
+            for part in name.split("/")}
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adamw"])
+def test_executables_carry_their_stage_names(optimizer):
+    """The worker's fused gradient and the server's stage and flush
+    executables name their stages (``jax.named_scope``): the compiled
+    HLO keeps them in each op's ``op_name``."""
+    import jax.numpy as jnp
+
+    from repro.core.slab import SlabAggregator, slab_codec
+    from repro.optim.slab_form import SlabOptimizer
+
+    rt = ClusterTrainer().build_runtime(_sync_spec(slab_dtype="bf16"))
+    p = rt.codec.encode(rt.init_params)
+    x, y = rt.x_tr[:16], rt.y_tr[:16]
+    assert {"decode", "loss_grad", "encode"} <= \
+        _op_names(rt._grad.lower(p, x, y).compile())
+
+    codec = slab_codec(rt.init_params, "bf16")
+    agg = SlabAggregator(codec, rt.init_params, 2,
+                         optimizer=SlabOptimizer(optimizer))
+    assert "stage" in _op_names(agg._stage.lower(
+        agg._staging, p, jnp.int32(0)).compile())
+    w, s = jnp.ones((2,), jnp.float32), jnp.float32(0.1)
+    if optimizer == "sgd":
+        flush = agg._flush.lower(agg._slab, agg._staging, w, s)
+    else:
+        state = [agg._moments[m] for m in agg.opt.moment_names]
+        flush = agg._flush_opt.lower(agg._slab, *state, agg._count,
+                                     agg._staging, w, s)
+    assert {"aggregate", "apply", "publish_cast"} <= \
+        _op_names(flush.compile())
+
+
+def test_named_stages_leave_the_sync_run_bitwise_unchanged(monkeypatch):
+    """Names are metadata: a sync run under a gradient budget with the
+    stage names compiled out ends on bit-identical parameters."""
+    import contextlib
+
+    spec = _sync_spec(slab_dtype="bf16")
+    named = ClusterTrainer()
+    assert named.run(spec).extra["accounting"]["applied"] == 12
+    jax.clear_caches()
+    try:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        plain = ClusterTrainer()
+        assert plain.run(spec).extra["accounting"]["applied"] == 12
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for key in named.last_params:
+        assert np.array_equal(np.asarray(named.last_params[key]),
+                              np.asarray(plain.last_params[key])), key
+
+
 # --------------------------------------------- ledger reconciliation
 
 @pytest.mark.parametrize("transport", ["inproc", "socket"])
@@ -171,11 +291,27 @@ def test_counters_reconcile_with_ledger(transport):
     res = run(_spec(transport=transport))
     tel = _check_reconcile(res)
     h = tel["histograms"]
+    a = res.extra["accounting"]
     # the instrumented seams produced samples: staleness per ingest,
-    # flush/publish per update, grad/send-wait per worker gradient
-    for name in ("staleness", "flush_s", "publish_s", "grad_s",
-                 "send_wait_s", "queue_depth"):
+    # flush dispatch/publish and the gap since the last per update,
+    # grad/send-wait per worker gradient, the ingest loop's waits
+    for name in ("staleness", "flush_dispatch_s", "publish_s",
+                 "publish_gap_s", "grad_s", "send_wait_s",
+                 "recv_wait_s"):
         assert h.get(name, {}).get("count", 0) > 0, name
+    assert h["flush_dispatch_s"]["count"] == a["updates"]
+    assert h["publish_gap_s"]["count"] == a["updates"]
+    # the in-process queue times every gradient it hands out (the
+    # window's and the post-window drain's); the wire carries no send
+    # time, so the socket hub has none
+    if transport == "inproc":
+        assert h["grad_queue_s"]["count"] == a["computed"]
+    else:
+        assert "grad_queue_s" not in h
+    # what nothing read is gone
+    for name in h:
+        assert name not in ("flush_s", "opt_update_s", "queue_depth")
+        assert not name.startswith(("staleness.w", "grad_s.w")), name
     assert tel["counters"].get("params_published", 0) > 0
 
 
@@ -233,7 +369,8 @@ def test_trace_on_off_bitwise_identical(tmp_path):
     assert grads_by_track.get("worker/0", 0) >= 1
     assert grads_by_track.get("worker/1", 0) >= 1
     names = [e["name"] for e in events if e.get("ph") == "X"]
-    assert names.count("flush") >= 1 and names.count("publish") >= 1
+    assert names.count("flush_dispatch") >= 1
+    assert names.count("publish") >= 1
 
 
 # -------------------------------------------- live stats plane (STATS)
