@@ -16,10 +16,13 @@ K(t)) against real concurrent workers:
 
 The aggregation hot path is the slab path end-to-end: workers ship
 ``(P,)`` gradient slabs (see :class:`~repro.cluster.transport.
-GradientMsg`), the server stages them into a preallocated
-``(K_max, P)`` buffer, and **one** jitted, donated executable
-(:class:`repro.core.slab.SlabAggregator`) applies every flush — any
-buffer size K, any fleet size, one compile.  The pre-slab server
+GradientMsg`), the server holds each in one of ``K_max`` staging slots
+— by reference when it is already on the device in the staging dtype,
+else after the one transfer or cast it needs (counted as
+``stage.held`` / ``stage.copied``) — and **one** jitted, donated
+executable (:class:`repro.core.slab.SlabAggregator`) applies every
+flush over the held rows — any buffer size K, any fleet size, one
+compile.  The pre-slab server
 compiled ``num_workers`` separate executables at startup and copied the
 full params pytree on every update; both costs are gone (the startup
 probe in ``tests/test_slab.py`` pins the executable count to 1).
@@ -82,9 +85,9 @@ class ParameterServer:
         # a flush aggregates at most one gradient per worker — except
         # async, where the policy is K ≡ 1 *by definition* (the
         # schedule is ignored; see _ingest_buffered), pinning the
-        # staging buffer to one row.  For hybrid, a schedule built for
-        # a larger fleet can demand K > num_workers, so the staging
-        # buffer covers the schedule's own ceiling too.
+        # staging to one slot.  For hybrid, a schedule built for a
+        # larger fleet can demand K > num_workers, so the staging slots
+        # cover the schedule's own ceiling too.
         if mode == "async":
             k_max = 1
         else:
@@ -103,9 +106,9 @@ class ParameterServer:
                                   use_pallas=use_pallas,
                                   interpret=interpret,
                                   optimizer=self.optimizer)
-        # compile the stage + flush executables before the clock starts
+        # compile the flush executable before the clock starts
         # (compiling mid-run would stall the whole fleet under the
-        # server lock) — one compile each, for any fleet size
+        # server lock) — one compile, for any fleet size
         self.agg.warmup()
         self.buffer = SlabBuffer(self.agg, staleness_decay)
         self.applied = 0                    # gradients folded into updates
@@ -117,7 +120,7 @@ class ParameterServer:
         #                                     tell a restore from a slow
         #                                     round (see ParamsMsg.epoch)
         # membership starts empty: workers register as they spawn
-        # (num_workers is the fleet size = the staging buffer's K_max)
+        # (num_workers is the fleet size = the staging slots' K_max)
         self.live: Set[int] = set()
         self._round: Dict[int, Any] = {}    # sync: worker_id -> grad slab
         self.done = threading.Event()       # max_gradients budget reached
@@ -132,7 +135,7 @@ class ParameterServer:
     def grow_fleet(self, num_workers: int,
                    schedule: Optional[ThresholdSchedule] = None) -> None:
         """Admit a fleet larger than construction time planned for
-        (elastic membership): grow the staging buffer to cover
+        (elastic membership): lengthen the staging slots to cover
         ``num_workers`` simultaneous contributions and, when a
         re-derived K(t) ``schedule`` for the new fleet size is handed
         in, swap it in atomically with the resize.  Must run *before*
@@ -214,7 +217,7 @@ class ParameterServer:
         wids = sorted(self._round)          # deterministic fold order
         with self.obs.span("server", "stage_dispatch", k=len(wids)):
             for slot, w in enumerate(wids):
-                self.agg.stage(self._round[w], slot)
+                self._count_stage(self.agg.stage(self._round[w], slot))
         k = len(wids)
         self._round = {}
         # sync: the plain mean of the round's gradients
@@ -222,8 +225,8 @@ class ParameterServer:
 
     def _ingest_buffered(self, msg: GradientMsg) -> None:
         with self.obs.span("server", "stage_dispatch", k=1):
-            self.buffer.add(msg.grad, msg.version)
-        # async is K ≡ 1 by definition (its one-row staging buffer
+            self._count_stage(self.buffer.add(msg.grad, msg.version))
+        # async is K ≡ 1 by definition (its one-slot staging
         # depends on it); hybrid asks the K(t) schedule
         k_needed = 1 if self.mode == "async" else \
             self.schedule(self.version)
@@ -243,6 +246,11 @@ class ParameterServer:
             # sync-style confident update — both are one fused scale
             scale = self.lr * k if self.flush_mode == "sum" else self.lr
             self._apply(weights, scale)
+
+    def _count_stage(self, held: bool) -> None:
+        # how often staging holds the gradient as is, and how often it
+        # pays a transfer or cast (a socket transport's host rows)
+        self.obs.count("stage.held" if held else "stage.copied")
 
     def _apply(self, weights: np.ndarray, scale: float) -> None:
         # host dispatch of the fused flush + optimizer step: the device
@@ -318,7 +326,7 @@ class ParameterServer:
         """Restore-into-running-server: replace the live params and
         version (so K(t) continues from ``step``), discarding any
         in-buffer or mid-round gradients (they were computed against a
-        history that no longer exists — and are *wiped*, not just
+        history that no longer exists — and are *dropped*, not just
         masked, because a diverged non-finite gradient would poison
         later flushes through ``0 · inf = nan``)."""
         with self.lock:

@@ -4,10 +4,12 @@ A *slab* is one contiguous ``(P_pad,)`` array holding every leaf of a
 pytree: leaves in ``jax.tree`` flatten order, raveled C-order,
 concatenated, and zero-padded so ``P_pad`` is a multiple of the Pallas
 flush tile (:data:`repro.kernels.hybrid_aggregate.TILE_P`).  Workers
-flatten a gradient **once** and ship the slab; the server stages
-incoming slabs into a preallocated ``(K_max, P_pad)`` buffer and applies
-every flush through **one** jitted, donated executable, regardless of
-how many gradients K the flush aggregates.  The same layout is what a
+flatten a gradient **once** and ship the slab; the server holds each
+incoming slab in one of ``K_max`` staging slots (a reference, not a
+copy, when the slab is already on the device in the staging dtype) and
+applies every flush through **one** jitted, donated executable that
+reads the ``K_max`` rows where they lie, regardless of how many
+gradients K the flush aggregates.  The same layout is what a
 multi-process transport puts on the wire (one buffer, no per-leaf
 framing).
 
@@ -27,18 +29,19 @@ Layout::
 
 Multi-million-parameter slabs can additionally be **sharded along P**
 into tile-aligned chunks (:class:`SlabAggregator` ``shards=``): each
-chunk gets its own staging buffer and donated flush executable (one per
-distinct chunk shape), placed round-robin across local devices, so a
+chunk is staged on, and flushed by its own donated executable on (one
+per distinct chunk shape), a local device in round-robin order, so a
 big model's staging traffic spreads across the host topology instead of
-funneling through one buffer.  ``shards=1`` (the default for small
-slabs) is the historical single-buffer path, bit for bit.
+funneling through one device.  ``shards=1`` (the default for small
+slabs) is the historical single-device path, bit for bit.
 
 Donation rules (enforced by :class:`SlabAggregator`, relied on by the
 cluster server):
 
-* the aggregator's private params slab and the staging buffer are
-  donated into their executables — they are updated in place and must
-  never escape the aggregator;
+* the aggregator's private params slab (and optimizer moments) are
+  donated into the flush — they are updated in place and must never
+  escape the aggregator.  Staged rows are read, never donated: a held
+  gradient slab is not written by the aggregator;
 * everything handed to callers (the published params slab, decoded
   trees) is a *fresh* executable output, never an alias of a donated
   buffer, so it stays valid across later flushes;
@@ -50,7 +53,8 @@ Backend matrix for the flush's inner reduction:
 
 ============  =======================================================
 TPU           :func:`repro.kernels.hybrid_aggregate.flush_pallas`
-              (masked: zero-weight rows beyond K contribute exactly 0)
+              over the K_max rows (masked: zero-weight slots beyond K
+              contribute exactly 0)
 CPU / other   jnp fallback — a statically unrolled masked fold, bitwise
               identical to the legacy per-leaf fold for uniform weights
 tests         the Pallas kernel under ``interpret=True``
@@ -242,8 +246,8 @@ def shard_chunks(padded_size: int, shards: int) -> Tuple[int, ...]:
 
 
 class SlabAggregator:
-    """Params slab + ``(K_max, P_pad)`` staging buffer + the **one**
-    donated fused flush executable (per staging chunk shape).
+    """Params slab + ``K_max`` staging slots + the **one** donated fused
+    flush executable (per chunk shape).
 
     The flush computes, for the first ``k`` staged rows ``g_i`` with
     weights ``w_i`` (zero-padded to ``K_max``)::
@@ -254,10 +258,19 @@ class SlabAggregator:
     *published* copy of the new params that is safe to hand to workers:
     it never aliases the donated buffer (guarded by a regression test in
     ``tests/test_slab.py``).  One executable serves every buffer size
-    ``1 <= k <= K_max`` purely through zero-weight masking of the
-    unused rows.  The jit cache is per-aggregator, so
+    ``1 <= k <= K_max`` purely through zero-weight masking: the flush
+    always takes ``K_max`` rows, and an empty slot passes slot 0's row
+    at weight 0.  The jit cache is per-aggregator, so
     ``flush_cache_size()`` is an exact probe that no per-K
     recompilation crept back in.
+
+    **Staging by reference**: a slot holds a ``(P_pad,)`` row, never a
+    slice of a matrix.  :meth:`stage` keeps the caller's slab itself
+    when it is already a device array in the staging dtype on the
+    staging device (a worker's fresh gradient output, in process): no
+    executable runs and nothing is copied.  Anything else takes the one
+    transfer or cast that staging needs.  A flush drops the rows it
+    consumed, so staging memory is the rows held between flushes.
 
     **Mixed precision**: staging rows and the published slab are in the
     codec's ``slab_dtype``; the master params slab is always float32 and
@@ -265,10 +278,10 @@ class SlabAggregator:
     executable).  With the default f32 codec every cast is a trace-time
     no-op and the path is bit-for-bit the historical one.
 
-    **Sharding**: ``shards > 1`` splits staging (and the master slab)
-    along P into tile-aligned chunks placed round-robin across local
-    devices — multi-million-parameter slabs stage across the host
-    topology instead of one buffer.  Chunking never changes the math:
+    **Sharding**: ``shards > 1`` splits each staged row (and the master
+    slab) along P into tile-aligned chunks placed round-robin across
+    local devices — multi-million-parameter slabs stage across the host
+    topology instead of one device.  Chunking never changes the math:
     the masked fold is elementwise along P, so the sharded flush is
     bitwise identical to the unsharded one.  ``shards=None`` picks
     automatically (1 unless the slab is huge and devices are plural).
@@ -278,7 +291,7 @@ class SlabAggregator:
     momentum's ``mu`` / AdamW's ``mu``/``nu`` moments are **f32** slabs
     shaped and sharded exactly like the master params (f32 even under a
     bf16 codec), donated into ONE fused flush+optimizer executable per
-    buffer shape, with AdamW's bias correction driven by the int32
+    chunk shape, with AdamW's bias correction driven by the int32
     update count carried in state (the convention shared with the
     pytree-form optimizers).  ``optimizer="sgd"`` (the default) keeps
     the historical executable untouched, bit for bit.
@@ -304,7 +317,6 @@ class SlabAggregator:
         self.chunk_offsets = tuple(int(o) for o in
                                    np.cumsum((0,) + self.chunk_sizes)[:-1])
         self._devices = jax.local_devices()
-        self._stage = jax.jit(self._stage_impl, donate_argnums=(0,))
         self._flush = jax.jit(self._flush_impl, donate_argnums=(0,))
         # the fused flush+optimizer executables: the params slab AND the
         # moment slabs are donated — updated in place, never escaping.
@@ -320,16 +332,14 @@ class SlabAggregator:
         else:
             self._flush_opt = None
         if self.shards == 1:
-            # historical single-buffer path, bit for bit
+            # historical single-device path, bit for bit
             self._slab = codec.encode_master(params)
-            self._staging = jnp.zeros((self.k_max, codec.padded_size),
-                                      codec.slab_dtype)
         else:
             self._slab = self._shard(codec.encode_master(params))
-            self._staging = [
-                jax.device_put(jnp.zeros((self.k_max, n),
-                                         codec.slab_dtype), d)
-                for n, d in zip(self.chunk_sizes, self._chunk_devices())]
+        # staging slots: None, or one staged row as a tuple of its
+        # per-chunk (n,) arrays
+        self._rows: List[Optional[Tuple[jax.Array, ...]]] = \
+            [None] * self.k_max
         # published params slab: always a fresh executable output
         self._pub = codec.encode(params)
         self._init_opt_state()
@@ -352,42 +362,21 @@ class SlabAggregator:
                                     self._chunk_devices())]
 
     # ------------------------------------------------------ executables
-    @staticmethod
-    def _stage_impl(staging, row, slot):
-        # donated: an in-place row write, not a buffer copy.  The cast
-        # is a trace-time no-op when the row already arrives in the
-        # staging dtype (the native-wire case)
-        with jax.named_scope("stage"):
-            if row.dtype != staging.dtype:
-                row = row.astype(staging.dtype)
-            return jax.lax.dynamic_update_slice(staging, row[None],
-                                                (slot, 0))
-
-    def _flush_impl(self, pslab, staging, weights, scale):
-        # both branches reduce via zero-weight masking — rows past the
-        # live count hold weight 0 and contribute exactly +0.0 — which
-        # is what lets ONE executable serve every buffer size k.  The
+    def _flush_impl(self, pslab, rows, weights, scale):
+        # ``rows`` are the K_max staged rows, each its own buffer; both
+        # branches reduce via zero-weight masking — slots past the live
+        # count hold weight 0 and contribute exactly +0.0 — which is
+        # what lets ONE executable serve every buffer size k.  The
         # reduction always runs in float32: the kernel upcasts bf16 rows
-        # per tile (widening the staging buffer in HBM first would cost
-        # K_max·P·4 bytes of scratch), the jnp fold upcasts here (for
-        # f32 rows the cast disappears at trace time)
+        # per tile, the jnp fold upcasts row by row (for f32 rows the
+        # cast disappears at trace time)
         with jax.named_scope("aggregate"):
             if self.use_pallas:
-                agg = flush_pallas(staging, weights,
+                agg = flush_pallas(rows, weights,
                                    out_dtype=jnp.float32,
                                    interpret=self.interpret)
             else:
-                # jnp fallback: a statically unrolled masked fold in
-                # staging order — structurally identical to the legacy
-                # per-leaf fold (same muls, same adds, same order),
-                # which keeps the sync round mean bitwise-equal to the
-                # pre-slab server.  (A fori_loop over only the k live
-                # rows compiles to different FMA contraction and drifts
-                # by 1 ulp.)
-                rows = staging.astype(jnp.float32)
-                agg = weights[0] * rows[0]
-                for i in range(1, self.k_max):
-                    agg = agg + weights[i] * rows[i]
+                agg = self._fold(rows, weights)
         with jax.named_scope("apply"):
             new = pslab - scale * (agg / jnp.sum(weights))
         # the second output is the published copy: a fresh buffer that
@@ -404,17 +393,20 @@ class SlabAggregator:
                 return new + 0.0
             return new.astype(self.codec.slab_dtype)
 
-    def _mean_grad(self, staging, weights):
-        """The flush's weighted-mean gradient on the jnp path: the same
-        statically unrolled masked f32 fold as the SGD flush (same muls,
-        adds, order — deterministic), normalized by the weight sum."""
-        rows = staging.astype(jnp.float32)
-        agg = weights[0] * rows[0]
-        for i in range(1, self.k_max):
-            agg = agg + weights[i] * rows[i]
-        return agg / jnp.sum(weights)
+    @staticmethod
+    def _fold(rows, weights):
+        """The jnp fallback's masked f32 fold, statically unrolled in
+        slot order — structurally identical to the legacy per-leaf fold
+        (same muls, same adds, same order), which keeps the sync round
+        mean bitwise-equal to the pre-slab server.  (A fori_loop over
+        only the k live rows compiles to different FMA contraction and
+        drifts by 1 ulp.)"""
+        agg = weights[0] * rows[0].astype(jnp.float32)
+        for i in range(1, len(rows)):
+            agg = agg + weights[i] * rows[i].astype(jnp.float32)
+        return agg
 
-    def _flush_momentum_impl(self, pslab, mu, count, staging, weights,
+    def _flush_momentum_impl(self, pslab, mu, count, rows, weights,
                              scale):
         # fused aggregate + heavy-ball momentum:  mu' = β·mu + ĝ ;
         # params' = params - scale·mu'.  ``pslab`` and ``mu`` are
@@ -422,7 +414,7 @@ class SlabAggregator:
         if self.use_pallas:
             with jax.named_scope("aggregate"):
                 upd, mu_new = flush_momentum_pallas(
-                    staging, weights / jnp.sum(weights), mu,
+                    rows, weights / jnp.sum(weights), mu,
                     self.opt.beta1, out_dtype=jnp.float32,
                     interpret=self.interpret)
             with jax.named_scope("apply"):
@@ -430,7 +422,7 @@ class SlabAggregator:
                 count_new = count + 1
         else:
             with jax.named_scope("aggregate"):
-                g = self._mean_grad(staging, weights)
+                g = self._fold(rows, weights) / jnp.sum(weights)
             with jax.named_scope("apply"):
                 upd, st = self._pair.update(
                     g, {"count": count, "mu": mu}, pslab)
@@ -438,7 +430,7 @@ class SlabAggregator:
                 mu_new, count_new = st["mu"], st["count"]
         return new, mu_new, count_new, self._published(new)
 
-    def _flush_adamw_impl(self, pslab, mu, nu, count, staging, weights,
+    def _flush_adamw_impl(self, pslab, mu, nu, count, rows, weights,
                           scale):
         # fused aggregate + AdamW with bias correction off the int32
         # count carried in state (the shared step-count convention of
@@ -451,7 +443,7 @@ class SlabAggregator:
                 bc1, bc2 = bias_correction(c, self.opt.beta1,
                                            self.opt.beta2)
                 new, mu_new, nu_new = flush_adamw_pallas(
-                    staging, weights / jnp.sum(weights), pslab, mu, nu,
+                    rows, weights / jnp.sum(weights), pslab, mu, nu,
                     bc1, bc2, scale, b1=self.opt.beta1,
                     b2=self.opt.beta2, eps=self.opt.eps,
                     weight_decay=self.opt.weight_decay,
@@ -459,7 +451,7 @@ class SlabAggregator:
                 count_new = c
         else:
             with jax.named_scope("aggregate"):
-                g = self._mean_grad(staging, weights)
+                g = self._fold(rows, weights) / jnp.sum(weights)
             with jax.named_scope("apply"):
                 upd, st = self._pair.update(
                     g, {"count": count, "mu": mu, "nu": nu}, pslab)
@@ -484,77 +476,83 @@ class SlabAggregator:
         return jnp.concatenate(
             [jax.device_put(c, self._devices[0]) for c in chunks])
 
+    def _flush_chunk(self, slab, moments, rows, w, s):
+        """One chunk's flush: (new master, new moments, new update
+        count, published chunk).  SGD runs the historical executable,
+        with no optimizer state in its arguments."""
+        if self.opt.name == "sgd":
+            new, pub = self._flush(slab, rows, w, s)
+            return new, [], self._count, pub
+        new, *state, count, pub = self._flush_opt(
+            slab, *moments, self._count, rows, w, s)
+        return new, state, count, pub
+
     # ------------------------------------------------------------- API
-    def stage(self, slab: jax.Array, slot: int) -> None:
-        """Write one gradient slab into staging row ``slot`` (in place)."""
+    def stage(self, slab, slot: int) -> bool:
+        """Hold one ``(P_pad,)`` gradient slab in staging slot ``slot``.
+
+        Returns True when the slab itself is held: it is a device array
+        in the staging dtype on the staging device, so nothing runs and
+        nothing is copied.  Otherwise returns False after the one
+        transfer (a host row, a row on another device, each chunk of a
+        sharded slab) or cast (a row in another dtype) staging needs.
+        Nothing the aggregator runs writes a held slab."""
         assert 0 <= slot < self.k_max, (slot, self.k_max)
-        slot_i = jnp.asarray(slot, jnp.int32)
-        if self.shards == 1:
-            self._staging = self._stage(self._staging, slab, slot_i)
-            return
-        slab = jnp.asarray(slab)
-        for i, (off, n) in enumerate(zip(self.chunk_offsets,
-                                         self.chunk_sizes)):
-            chunk = jax.device_put(slab[off:off + n],
-                                   self._staging[i].devices().pop())
-            self._staging[i] = self._stage(self._staging[i], chunk,
-                                           slot_i)
+        if np.shape(slab) != (self.codec.padded_size,):
+            raise ValueError(f"a gradient slab of shape {np.shape(slab)} "
+                             f"cannot stage into ({self.codec.padded_size},)"
+                             " rows")
+        sdt = self.codec.slab_dtype
+        chunks = [self._slab] if self.shards == 1 else self._slab
+        devices = [next(iter(c.devices())) for c in chunks]
+        if (self.shards == 1 and isinstance(slab, jax.Array)
+                and slab.dtype == sdt
+                and slab.sharding.device_set == {devices[0]}):
+            self._rows[slot] = (slab,)
+            return True
+        rows = []
+        for off, n, d in zip(self.chunk_offsets, self.chunk_sizes,
+                             devices):
+            row = jax.device_put(
+                slab if self.shards == 1 else slab[off:off + n], d)
+            rows.append(row if row.dtype == sdt else row.astype(sdt))
+        self._rows[slot] = tuple(rows)
+        return False
 
     def flush_apply(self, weights: np.ndarray, scale: float) -> jax.Array:
-        """Aggregate the first ``len(weights)`` staged rows and apply the
-        update.  Returns the freshly published params slab."""
+        """Aggregate the first ``len(weights)`` staged rows, apply the
+        update and drop every staged row.  Returns the freshly published
+        params slab."""
         k = len(weights)
         assert 1 <= k <= self.k_max, (k, self.k_max)
+        live = self._rows[:k]
+        assert all(r is not None for r in live), \
+            f"flush of {k} rows with an empty slot among them"
+        # every empty slot passes slot 0's row at weight 0: the
+        # executable's K_max operands are live rows, none kept for it
+        slots = live + [live[0]] * (self.k_max - k)
+        self._rows = [None] * self.k_max
         wfull = np.zeros((self.k_max,), np.float32)
         wfull[:k] = np.asarray(weights, np.float32)
         w = jnp.asarray(wfull)
         s = jnp.asarray(scale, jnp.float32)
-        if self.opt.name == "sgd":
-            # the historical path, bit for bit: same executable, same
-            # arguments, no optimizer-state plumbing in the trace
-            if self.shards == 1:
-                self._slab, self._pub = self._flush(self._slab,
-                                                    self._staging, w, s)
-                return self._pub
-            pubs = []
-            for i in range(self.shards):
-                self._slab[i], pub = self._flush(self._slab[i],
-                                                 self._staging[i], w, s)
-                pubs.append(pub)
-            self._pub = self._assemble(pubs)
-            return self._pub
-        if self.opt.name == "momentum":
-            mu = self._moments["mu"]
-            if self.shards == 1:
-                self._slab, self._moments["mu"], self._count, self._pub \
-                    = self._flush_opt(self._slab, mu, self._count,
-                                      self._staging, w, s)
-                return self._pub
-            pubs = []
-            cnt = self._count
-            for i in range(self.shards):
-                self._slab[i], mu[i], cnt, pub = self._flush_opt(
-                    self._slab[i], mu[i], self._count,
-                    self._staging[i], w, s)
-                pubs.append(pub)
-            self._count = cnt
-            self._pub = self._assemble(pubs)
-            return self._pub
-        # adamw
-        mu, nu = self._moments["mu"], self._moments["nu"]
+        names = self.opt.moment_names
         if self.shards == 1:
-            (self._slab, self._moments["mu"], self._moments["nu"],
-             self._count, self._pub) = self._flush_opt(
-                self._slab, mu, nu, self._count, self._staging, w, s)
+            self._slab, moments, self._count, self._pub = \
+                self._flush_chunk(self._slab,
+                                  [self._moments[n] for n in names],
+                                  tuple(r[0] for r in slots), w, s)
+            self._moments = dict(zip(names, moments))
             return self._pub
-        pubs = []
-        cnt = self._count
+        pubs, count = [], self._count
         for i in range(self.shards):
-            self._slab[i], mu[i], nu[i], cnt, pub = self._flush_opt(
-                self._slab[i], mu[i], nu[i], self._count,
-                self._staging[i], w, s)
+            self._slab[i], moments, count, pub = self._flush_chunk(
+                self._slab[i], [self._moments[n][i] for n in names],
+                tuple(r[i] for r in slots), w, s)
+            for n, m in zip(names, moments):
+                self._moments[n][i] = m
             pubs.append(pub)
-        self._count = cnt
+        self._count = count
         self._pub = self._assemble(pubs)
         return self._pub
 
@@ -619,22 +617,18 @@ class SlabAggregator:
         return out
 
     def wipe_staging(self) -> None:
-        """Zero every staging row.  Needed when staged gradients are
-        *discarded* rather than consumed by a flush: zero-weight masking
-        neutralizes any finite leftover, but a non-finite row (a
-        diverged gradient the restore is recovering from) would poison
-        later flushes — ``0 · inf = nan``."""
-        if self.shards == 1:
-            self._staging = jnp.zeros_like(self._staging)
-        else:
-            self._staging = [jnp.zeros_like(c) for c in self._staging]
+        """Drop every staged row unconsumed.  A discarded row is gone,
+        not masked: it may be non-finite (a diverged gradient the
+        restore is recovering from), and no later flush can meet it with
+        a zero weight (``0 · inf = nan``)."""
+        self._rows = [None] * self.k_max
 
     def warmup(self) -> None:
-        """Compile the stage + flush executables before the clock starts
-        (one compile each per chunk shape, for any fleet size — vs the
-        pre-slab server's one compile per K in 1..num_workers).  The
-        warmup flush uses scale=0 over a zero row, so the params are
-        bitwise unchanged."""
+        """Compile the flush executable before the clock starts (one
+        compile per chunk shape, for any fleet size — vs the pre-slab
+        server's one compile per K in 1..num_workers).  The warmup flush
+        uses scale=0 over a zero row, so the params are bitwise
+        unchanged."""
         self.stage(jnp.zeros((self.codec.padded_size,),
                              self.codec.slab_dtype), 0)
         self.flush_apply(np.ones((1,), np.float32), 0.0)
@@ -645,39 +639,26 @@ class SlabAggregator:
             self._count = jnp.zeros((), jnp.int32)
 
     def grow(self, k_max: int) -> None:
-        """Resize the staging buffer to ``k_max`` rows (elastic fleet
-        admission).  Already-staged rows are preserved — a hybrid buffer
-        keeps gradients staged *between* flushes, so growth mid-buffer
-        must not lose them — and the new rows are zero, which the
-        zero-weight masking keeps inert.  No warmup flush runs here (it
+        """Lengthen the staging slots to ``k_max`` (elastic fleet
+        admission).  Already-staged rows stay in their slots — a hybrid
+        buffer keeps gradients staged *between* flushes, so growth
+        mid-buffer must not lose them.  No warmup flush runs here (it
         would fold staged row 0 into the params); the next real flush
-        traces the new shape, so growth costs one compile per resize —
-        paid only by elastic fleets, never by a fixed one.
-        Shrinking is never done: a departed worker's row just keeps
-        weight 0."""
+        traces the new operand count, so growth costs one compile per
+        resize — paid only by elastic fleets, never by a fixed one.
+        Shrinking is never done: a departed worker's slot just stays
+        empty."""
         k_max = int(k_max)
         if k_max <= self.k_max:
             return
+        self._rows += [None] * (k_max - self.k_max)
         self.k_max = k_max
-        if self.shards == 1:
-            old = self._staging
-            self._staging = jnp.zeros(
-                (k_max, self.codec.padded_size),
-                self.codec.slab_dtype).at[:old.shape[0]].set(old)
-        else:
-            self._staging = [
-                jax.device_put(
-                    jnp.zeros((k_max, old.shape[1]),
-                              self.codec.slab_dtype
-                              ).at[:old.shape[0]].set(old),
-                    old.devices().pop())
-                for old in self._staging]
 
     def flush_cache_size(self) -> int:
         """Number of compiled flush executables (the probe asserted to
         be exactly 1 in tests for the unsharded default, regardless of
         fleet size / K — growth via :meth:`grow` adds one entry per
-        resize, and sharded staging holds one entry per distinct chunk
+        resize, and sharded slabs hold one entry per distinct chunk
         shape).  With a moment-carrying optimizer the probe covers the
         fused flush+optimizer executable instead — still exactly one
         per buffer shape."""
@@ -704,9 +685,12 @@ class SlabBuffer:
     def __len__(self) -> int:
         return len(self._versions)
 
-    def add(self, slab: jax.Array, version: int) -> None:
-        self.agg.stage(slab, len(self._versions))
+    def add(self, slab, version: int) -> bool:
+        """Stage ``slab`` in the next slot; True when the slab itself is
+        held (:meth:`SlabAggregator.stage`)."""
+        held = self.agg.stage(slab, len(self._versions))
         self._versions.append(int(version))
+        return held
 
     def weights(self, current_version: int) -> np.ndarray:
         """Staleness weights ``decay^(now - v_i)`` for the staged rows.
@@ -719,14 +703,13 @@ class SlabBuffer:
         return self.staleness_decay ** stale
 
     def clear(self) -> None:
-        """Forget rows that a flush just **consumed** (no wipe needed:
-        consumed rows are finite values already folded into the params,
-        and zero weights mask them on the next flush)."""
+        """Forget rows that a flush just **consumed** (the flush has
+        already dropped them from the aggregator)."""
         self._versions = []
 
     def discard(self) -> None:
         """Drop staged rows **unconsumed** (checkpoint restore).  The
-        rows are wiped, not just masked: a discarded gradient may be
+        rows are dropped, not just masked: a discarded gradient may be
         non-finite — that divergence can be exactly what the restore is
         recovering from — and ``0 · inf = nan`` would defeat the
         masking on every later flush."""

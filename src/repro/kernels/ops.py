@@ -17,8 +17,7 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.hybrid_aggregate import (flush_adamw_pallas,
                                             flush_momentum_pallas,
-                                            flush_pallas,
-                                            flush_pallas_sharded, TILE_P)
+                                            flush_pallas)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
 
@@ -61,21 +60,6 @@ def hybrid_flush(grads: jax.Array, weights: jax.Array, *,
         return ref.flush_ref(grads, weights)
     return flush_pallas(grads, weights,
                         interpret=_auto_interpret(interpret))
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def hybrid_flush_sharded(grad_chunks, weights: jax.Array, *,
-                         use_pallas: bool = True,
-                         interpret: Optional[bool] = None):
-    """Sharded weighted aggregation: a tuple/list of (K, P_i) staging
-    chunks (the tile-aligned P-split of one (K, P) slab) -> a list of
-    (P_i,) reduced chunks.  Per-chunk reduction keeps one compiled
-    executable per distinct chunk shape; concatenating the outputs is
-    bitwise identical to :func:`hybrid_flush` on the unsplit slab."""
-    if not use_pallas:
-        return [ref.flush_ref(g, weights) for g in grad_chunks]
-    return flush_pallas_sharded(grad_chunks, weights,
-                                interpret=_auto_interpret(interpret))
 
 
 @functools.partial(jax.jit,

@@ -124,3 +124,37 @@ def test_flush_matches_buffer_oracle():
     for k in ("a", "b"):
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("K", [1, 3])
+@pytest.mark.parametrize("kernel", ["flush", "momentum", "adamw"])
+def test_rows_form_matches_stacked_form(kernel, K):
+    """Each kernel takes its K gradient rows either stacked in a (K, P)
+    matrix or as K separate (P,) buffers (the server's held slabs): the
+    two forms compute the same flush, to the rounding of a K-axis
+    reduction against a row-by-row fold."""
+    from repro.kernels.hybrid_aggregate import (flush_adamw_pallas,
+                                                flush_momentum_pallas,
+                                                flush_pallas)
+    P = 2 * TILE_P
+    g = jax.random.normal(jax.random.PRNGKey(K), (K, P)).astype(
+        jnp.bfloat16)
+    w = jax.random.uniform(jax.random.PRNGKey(K + 1), (K,)) + 0.1
+    w = w / jnp.sum(w)
+    p = jax.random.normal(jax.random.PRNGKey(2), (P,))
+    m = 0.1 * p
+    v = 0.01 * jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (P,)))
+
+    def call(grads):
+        if kernel == "flush":
+            return (flush_pallas(grads, w, out_dtype=jnp.float32, **I),)
+        if kernel == "momentum":
+            return flush_momentum_pallas(grads, w, m, 0.9,
+                                         out_dtype=jnp.float32, **I)
+        return flush_adamw_pallas(grads, w, p, m, v, 0.1, 0.05, 0.01,
+                                  b1=0.9, b2=0.95, eps=1e-8,
+                                  weight_decay=0.01, **I)
+
+    for got, want in zip(call([g[i] for i in range(K)]), call(g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-7)

@@ -6,8 +6,10 @@ alignment, VMEM limits, HBM that a program does not fit in).  The TPU
 compiler is installed without a chip, so each case compiles for a
 *described* v5e chip and asserts the kernel was lowered as a Mosaic
 custom call.  The arguments are shapes only, at the slab length the
-cluster server stages for ``zoo:xlstm`` at ``zoo_scale=1.0``; the
-moment-carrying kernels donate what the server donates.
+cluster server stages for ``zoo:xlstm`` at ``zoo_scale=1.0``, in both
+operand forms: a stacked ``(K, P)`` matrix (the SPMD merge) and K
+separate ``(P,)`` rows (the server's held slabs); the moment-carrying
+kernels donate what the server donates.
 
 The topology is described inside a fixture (never at import): one
 process at a time may load the TPU library, and every pytest worker
@@ -89,14 +91,28 @@ KERNELS = {
 }
 
 
-@pytest.mark.parametrize("K", [1, 2, 4])
-@pytest.mark.parametrize("kernel", sorted(KERNELS))
-def test_flush_kernel_compiles_for_v5e(kernel, K, one_chip, padded_p,
-                                       no_compile_cache):
+def _compiles(kernel, K, one_chip, padded_p, rows_form):
     fn, dtype, n_slabs, n_scalars, donate = KERNELS[kernel]
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    args = ([sds((K, padded_p), dtype), sds((K,), jnp.float32)]
+    rows = [sds((padded_p,), dtype)] * K if rows_form \
+        else sds((K, padded_p), dtype)
+    args = ([rows, sds((K,), jnp.float32)]
             + [sds((padded_p,), jnp.float32)] * n_slabs
             + [sds((), jnp.float32)] * n_scalars)
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("K", [1, 2, 4])
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_flush_kernel_compiles_for_v5e(kernel, K, one_chip, padded_p,
+                                       no_compile_cache):
+    _compiles(kernel, K, one_chip, padded_p, rows_form=False)
+
+
+@pytest.mark.parametrize("K", [1, 2, 4])
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_flush_kernel_rows_form_compiles_for_v5e(kernel, K, one_chip,
+                                                 padded_p,
+                                                 no_compile_cache):
+    _compiles(kernel, K, one_chip, padded_p, rows_form=True)

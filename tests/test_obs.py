@@ -232,9 +232,10 @@ def _op_names(compiled) -> set:
 
 @pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adamw"])
 def test_executables_carry_their_stage_names(optimizer):
-    """The worker's fused gradient and the server's stage and flush
-    executables name their stages (``jax.named_scope``): the compiled
-    HLO keeps them in each op's ``op_name``."""
+    """The worker's fused gradient and the server's flush executables
+    name their stages (``jax.named_scope``): the compiled HLO keeps them
+    in each op's ``op_name``.  Staging runs no executable: the flush,
+    lowered over the held rows, is the aggregator's only one."""
     import jax.numpy as jnp
 
     from repro.core.slab import SlabAggregator, slab_codec
@@ -249,15 +250,18 @@ def test_executables_carry_their_stage_names(optimizer):
     codec = slab_codec(rt.init_params, "bf16")
     agg = SlabAggregator(codec, rt.init_params, 2,
                          optimizer=SlabOptimizer(optimizer))
-    assert "stage" in _op_names(agg._stage.lower(
-        agg._staging, p, jnp.int32(0)).compile())
+    jitted = {name for name, v in vars(agg).items() if hasattr(v, "lower")}
+    assert jitted == ({"_flush"} if optimizer == "sgd"
+                      else {"_flush", "_flush_opt"})
+    assert agg.stage(p, 0) and agg.stage(p, 1)
+    rows = tuple(r for r, in agg._rows)
     w, s = jnp.ones((2,), jnp.float32), jnp.float32(0.1)
     if optimizer == "sgd":
-        flush = agg._flush.lower(agg._slab, agg._staging, w, s)
+        flush = agg._flush.lower(agg._slab, rows, w, s)
     else:
         state = [agg._moments[m] for m in agg.opt.moment_names]
         flush = agg._flush_opt.lower(agg._slab, *state, agg._count,
-                                     agg._staging, w, s)
+                                     rows, w, s)
     assert {"aggregate", "apply", "publish_cast"} <= \
         _op_names(flush.compile())
 
@@ -313,6 +317,20 @@ def test_counters_reconcile_with_ledger(transport):
         assert name not in ("flush_s", "opt_update_s", "queue_depth")
         assert not name.startswith(("staleness.w", "grad_s.w")), name
     assert tel["counters"].get("params_published", 0) > 0
+
+
+@pytest.mark.parametrize("transport", ["inproc", "socket"])
+def test_stage_counts_held_and_copied_gradients(transport):
+    """In process, every staged bf16 gradient is the worker's own device
+    slab, held as is (``stage.held``); a socket transport hands the
+    server host rows, each copied to the device (``stage.copied``)."""
+    res = run(_spec(transport=transport, slab_dtype="bf16"))
+    a = res.extra["accounting"]
+    c = _check_reconcile(res)["counters"]
+    staged = a["applied"] + a["buffered"]
+    assert staged > 0
+    want = {"inproc": (staged, 0), "socket": (0, staged)}[transport]
+    assert (c.get("stage.held", 0), c.get("stage.copied", 0)) == want, c
 
 
 def test_counters_reconcile_with_ledger_proc():

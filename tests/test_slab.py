@@ -15,6 +15,7 @@ from repro.cluster.server import ParameterServer
 from repro.cluster.transport import GradientMsg, ParamsMsg
 from repro.core.schedule import constant_schedule, step_schedule
 from repro.kernels.hybrid_aggregate import TILE_P
+from repro.optim import SlabOptimizer
 
 
 def _tree(seed: int, scale: float = 1.0, shapes=None):
@@ -162,6 +163,95 @@ def test_slab_flush_pallas_interpret_matches_jnp():
         outs.append(np.asarray(
             agg.flush_apply(np.array([1.0, 0.9, 0.81]), 0.03)))
     np.testing.assert_allclose(outs[0], outs[1], rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------------- staging by reference
+
+@pytest.mark.parametrize("slab_dtype,source,held", [
+    ("f32", "device f32", True),
+    ("bf16", "device bf16", True),
+    ("bf16", "host bf16", False),
+    ("bf16", "host f32", False),
+    ("bf16", "device f32", False),
+])
+def test_stage_holds_device_rows_and_copies_the_rest(slab_dtype, source,
+                                                      held, caplog):
+    """A device slab already in the staging dtype is held as the very
+    array: nothing is compiled, run or copied.  A host row (a socket
+    transport's) or a row in another dtype takes one transfer or cast
+    into a fresh device row of the staging dtype."""
+    params = _tree(0)
+    codec = slab_codec(params, slab_dtype)
+    agg = SlabAggregator(codec, params, k_max=2)
+    where, dtype = source.split()
+    row = slab_codec(params, dtype).encode(_tree(1, 0.01))
+    jax.block_until_ready(row)
+    if where == "host":
+        row = np.asarray(row)
+    caplog.clear()
+    with jax.log_compiles(True):
+        assert agg.stage(row, 1) is held
+    staged, = agg._rows[1]
+    assert agg._rows[0] is None
+    if held:
+        assert staged is row
+        assert not [r for r in caplog.records
+                    if "Compiling" in r.getMessage()]
+        return
+    assert isinstance(staged, jax.Array) and staged is not row
+    assert staged.dtype == codec.slab_dtype
+    np.testing.assert_array_equal(
+        np.asarray(staged, np.float32),
+        np.asarray(jnp.asarray(row).astype(codec.slab_dtype), np.float32))
+
+
+@pytest.mark.parametrize("drop", ["flush", "discard"])
+def test_aggregator_releases_rows_it_no_longer_needs(drop):
+    """Rows a flush consumed, or a restore discarded, are no longer
+    referenced by the aggregator: their device memory goes when the
+    caller lets go of them."""
+    import gc
+    import weakref
+    params = _tree(0)
+    codec = slab_codec(params)
+    agg = SlabAggregator(codec, params, k_max=3)
+    buf = SlabBuffer(agg)
+    rows = [codec.encode(_tree(i + 1, 0.01)) for i in range(2)]
+    for r in rows:
+        assert buf.add(r, 0)
+    refs = [weakref.ref(r) for r in rows]
+    if drop == "flush":
+        jax.block_until_ready(agg.flush_apply(buf.weights(0), 0.05))
+        buf.clear()
+    else:
+        buf.discard()
+    assert agg._rows == [None] * 3 and len(buf) == 0
+    del rows, r
+    gc.collect()
+    assert all(ref() is None for ref in refs)
+
+
+def test_grow_keeps_staged_rows():
+    """Growing the slots mid-buffer keeps the rows already staged, as
+    they were, and the grown aggregator's flush is bitwise that of one
+    built at the larger K_max."""
+    params = _tree(0)
+    codec = slab_codec(params)
+    rows = [codec.encode(_tree(i + 1, 0.01)) for i in range(3)]
+    agg = SlabAggregator(codec, params, k_max=2)
+    agg.stage(rows[0], 0)
+    agg.stage(rows[1], 1)
+    agg.grow(4)
+    assert agg.k_max == 4 and len(agg._rows) == 4
+    assert agg._rows[0][0] is rows[0] and agg._rows[1][0] is rows[1]
+    agg.stage(rows[2], 2)
+    got = agg.flush_apply(np.array([1.0, 0.9, 0.8]), 0.05)
+    fixed = SlabAggregator(codec, params, k_max=4)
+    for i, r in enumerate(rows):
+        fixed.stage(r, i)
+    want = fixed.flush_apply(np.array([1.0, 0.9, 0.8]), 0.05)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert agg.flush_cache_size() == 1
 
 
 def test_slab_buffer_staleness_weights_clamped():
@@ -431,7 +521,6 @@ def test_exactly_one_flush_executable_any_fleet(num_workers):
 # ------------------------------------------- slab-resident optimizers
 
 from repro.core.slab import slab_codec as _slab_codec  # noqa: E402
-from repro.optim import SlabOptimizer  # noqa: E402
 
 OPTS = [SlabOptimizer("sgd"),
         SlabOptimizer("momentum", beta1=0.9),
@@ -502,6 +591,49 @@ def test_sim_and_cluster_sync_flush_bitwise_identical(opt):
             np.testing.assert_array_equal(st_server[mname],
                                           st_sim[mname],
                                           err_msg=f"{opt.name}:{mname}")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("opt", OPTS, ids=lambda o: o.name)
+def test_flush_below_k_max_matches_the_masked_fold(opt, use_pallas):
+    """k < K_max: the empty slot passes slot 0's row at weight 0.  Two
+    flushes give what the pre-change masked fold gave — the same
+    executable over a ``(K_max, P)`` matrix whose empty row is zeros:
+    bitwise on the jnp path; within the fold's rounding in the kernel,
+    whose stacked form reduces along K where the rows form folds row
+    by row (the two contract to FMAs differently on the CPU)."""
+    params = _tree(0)
+    codec = slab_codec(params, "bf16")
+    kw = dict(use_pallas=use_pallas, interpret=use_pallas, optimizer=opt)
+    agg = SlabAggregator(codec, params, k_max=3, **kw)
+    ref = SlabAggregator(codec, params, k_max=3, **kw)
+    impl = {"sgd": ref._flush_impl, "momentum": ref._flush_momentum_impl,
+            "adamw": ref._flush_adamw_impl}[opt.name]
+    impl = jax.jit(impl)
+    w = np.array([1.0, 0.9], np.float32)
+    w_pad, s = jnp.asarray([1.0, 0.9, 0.0], jnp.float32), jnp.float32(0.05)
+    state = [ref._moments[n] for n in opt.moment_names]
+    count, slab = ref._count, ref._slab
+    for step in range(2):
+        rows = [codec.encode(_tree(10 * step + i, 0.01)) for i in range(2)]
+        for i, r in enumerate(rows):
+            agg.stage(r, i)
+        got = agg.flush_apply(w, 0.05)
+        matrix = jnp.stack(rows + [jnp.zeros_like(rows[0])])
+        if opt.name == "sgd":
+            slab, want = impl(slab, matrix, w_pad, s)
+        else:
+            slab, *state, count, want = impl(slab, *state, count, matrix,
+                                             w_pad, s)
+    pairs = [(agg._slab, slab), (got, want)] + list(zip(
+        [agg._moments[n] for n in opt.moment_names], state))
+    for a, b in pairs:
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if use_pallas:
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+        else:
+            np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("opt", OPTS[1:], ids=lambda o: o.name)
