@@ -19,9 +19,10 @@ TINY = {"num_groups": 1, "d_model": 64, "vocab_size": 256, "num_heads": 4,
         "num_kv_heads": 2, "head_dim": 16, "d_ff": 96}
 
 
-def tiny_cell(workload: str, **traffic):
-    """The cell of ``workload`` with the TINY model, its parameter count
-    recomputed; ``traffic`` overrides the traffic's fields."""
+def tiny_cell(workload: str, config=None, setting=TINY, **traffic):
+    """The cell of ``workload`` with its configuration, or ``config`` in
+    its place, cut to ``setting`` and its parameter count recomputed;
+    ``traffic`` overrides the traffic's fields."""
     import jax
 
     import harness
@@ -29,9 +30,9 @@ def tiny_cell(workload: str, **traffic):
     from repro.models import model as M
 
     c = copy.deepcopy(harness.cell(workload))
-    conf = c["config"]
-    conf["set"] = dict(conf["set"], **TINY)
-    conf["shape"].update(TINY)
+    conf = c["config"] = copy.deepcopy(config) if config else c["config"]
+    conf["set"] = dict(conf["set"], **setting)
+    conf["shape"].update(setting)
     cfg = dataclasses.replace(get_config(conf["registry"]), **conf["set"])
     shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0),
                                                   cfg))

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 CELL = "danube4l.k2.t2048"
-SECONDS = 2.0
+SECONDS = 5.0
 
 
 def _run(tiny, **traffic):

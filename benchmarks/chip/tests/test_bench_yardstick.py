@@ -71,8 +71,9 @@ def test_each_configuration_builds_its_stated_count():
         with open(os.path.join(ROOT, c["file"])) as f:
             conf = json.load(f)
         # refuses a configuration that builds another count than its own
-        cfg = harness.model_config(conf)
-        assert cfg.num_layers == conf["set"]["num_groups"]
+        harness.model_config(conf)
+        # ... and every field it sets is one that it checks
+        assert set(conf["set"]) <= set(conf["shape"])
         assert set(conf["reduced"]) == set(c["reduced"])
 
 
@@ -112,6 +113,34 @@ def test_a_block_with_no_count_reads_nothing():
     other = dict(shape, block_pattern=[["attn", "mlp"], ["unknown", "none"]])
     assert flops.matmul_params(other) is None
     assert flops.train_flops_per_token(other, 32) is None
+
+
+@pytest.mark.parametrize("flushes, want", [
+    ([(1.0, 2), (2.0, 2), (3.0, 2)], 14.0),
+    ([(1.0, 2), (2.0, 2), (3.0, 2), (9.0, 1)], 14.0),
+    ([(0.5, 2), (9.0, 2)], None),
+], ids=["three_flushes_of_two", "a_flush_outside_the_window",
+        "no_flush_in_the_window"])
+def test_server_update_ms_reads_flush_time_per_applied_gradient(flushes,
+                                                                want):
+    """84 ms of flush-apply device time over 3 calls in a window from
+    0.9 to 3.5 s on the host clock, over the mean number of gradients
+    that the flushes published in it applied."""
+    import types
+
+    import harness
+    import xplane
+
+    summary = xplane.Summary(
+        window=(0, 2_600_000_000), devices=1, busy_s=2.0,
+        module_s={"jit__flush_impl": 0.084, "jit__grad_slab": 1.0},
+        module_n={"jit__flush_impl": 3, "jit__grad_slab": 6},
+        op_s={}, idle_by_span={})
+    rec = types.SimpleNamespace(
+        summary=summary, out={"trace_mono": (0.9, 3.5),
+                              "flush_times": flushes})
+    got = harness.load_module("metrics", "server_update_ms").read(rec)
+    assert got == (None if want is None else pytest.approx(want))
 
 
 def test_peaks_know_the_v5e_and_refuse_other_chips():
